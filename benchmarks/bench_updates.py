@@ -72,7 +72,7 @@ def run(n: int = 100_000, deg: float = 8.0, k: int = 1, batch_edges: int = 1000,
     # sanity: both paths answer identically on device (XLA path, CPU-safe)
     got = np.asarray(ej.query_dbindex(plan2, g2.attrs["val"], "sum", use_pallas=False))
     ref = np.asarray(ej.query_dbindex(
-        ej.plan_from_dbindex(idx2, block_capacity=plan2.block_capacity),
+        ej.plan_from_dbindex(idx2, like=plan2),
         g2.attrs["val"], "sum", use_pallas=False))
     assert np.array_equal(got, ref), "patched plan diverged from fresh plan"
 

@@ -17,6 +17,9 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default=None, help="comma list of module names")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_async_service,
         bench_audit,

@@ -10,11 +10,12 @@ the paper leans on.
 The registry is **open**: :func:`register_aggregate` adds a new aggregate as
 a set of monoid channels over the three channel *sources* — ``"value"`` (the
 attribute vector), ``"ones"`` (cardinality), ``"square"`` (the squared
-attribute) — plus a pure ``finalize(xp, *chans)`` where ``xp`` is ``numpy``
-or ``jax.numpy``.  Because every engine executes aggregates through the
-shared channel machinery (:class:`ChannelPack`), a registered aggregate
-immediately compiles to extra fused channels on the device executors, the
-sharded runtime and the serving layer — no core file edits.
+attribute) — plus a pure ``finalize(xp, *chans)``, written against the
+array namespace ``xp`` and run on the host with ``xp = numpy``.  Because
+every engine executes aggregates through the shared channel machinery
+(:class:`ChannelPack`), a registered aggregate immediately compiles to
+extra fused channels on the device executors, the sharded runtime and the
+serving layer — no core file edits.
 
 Dtype discipline: monoid channels preserve the integer/float class of the
 input attribute.  Integer attributes ride int64 channels with per-dtype
@@ -89,8 +90,8 @@ class Aggregate:
     ``var`` and ``l2`` share (sum, square).
 
     ``finalize(xp, *chans)`` must be pure array code written against the
-    ``xp`` namespace (``numpy`` on host, ``jax.numpy`` inside jitted fused
-    executors) so one definition serves both bit-identically.
+    ``xp`` namespace.  Every engine runs it on the host with ``numpy`` over
+    its reduced channels, so all engines and the oracle round alike.
     """
 
     name: str
@@ -111,10 +112,7 @@ class Aggregate:
         return tuple(_channel_input(v, src) for src in self.channel_sources)
 
     def finalize_np(self, *chans):
-        return self.finalize_xp(np, *chans)
-
-    def finalize_xp(self, xp, *chans):
-        return self.finalize(xp, *chans) if self.finalize else chans[0]
+        return self.finalize(np, *chans) if self.finalize else chans[0]
 
 
 def _channel_input(v: np.ndarray, src: str) -> np.ndarray:
@@ -262,15 +260,15 @@ class ChannelPack:
         v = values.astype(promote_channel_dtype(values))
         return tuple(_channel_input(v, src) for _, src in self.channels)
 
-    def finalize(self, agg_i: int, chans: Sequence, xp=np):
-        """Finalize aggregate ``agg_i`` from the reduced channel results.
-
-        ``xp`` is ``numpy`` or ``jax.numpy`` so the registered pure
-        finalizer (the Gray et al. algebraic decomposition) serves both the
-        host and device executors bit-identically.
-        """
-        picked = [chans[j] for j in self.agg_channels[agg_i]]
-        return AGGREGATES[self.aggs[agg_i]].finalize_xp(xp, *picked)
+    def finalize(self, chans: Sequence) -> tuple:
+        """Every aggregate's result, one NumPy array each, finalized on the
+        host from the reduced channels by its registered pure finalizer —
+        the oracle's own arithmetic.  A device finalize would not match it:
+        the TPU divides through a reciprocal that is not correctly rounded."""
+        host = [np.asarray(c) for c in chans]
+        return tuple(
+            AGGREGATES[a].finalize_np(*[host[j] for j in self.agg_channels[i]])
+            for i, a in enumerate(self.aggs))
 
 
 def pack_channels(aggs: Sequence[str]) -> ChannelPack:

@@ -255,22 +255,25 @@ def _pick(opts: dict, *names) -> dict:
 # ---------------------------------------------------------------------- #
 #  Batched fused executors (serving traffic)
 # ---------------------------------------------------------------------- #
-# jit(vmap(fused query)) per device engine, built lazily so the module
+# jit(lax.map(fused query)) per device engine, built lazily so the module
 # stays JAX-light.  The scheduler in repro.serve.window_service pads every
 # launch to a fixed [bucket, n] shape, so each executor compiles once and
-# is reused for every flush (the recompile counter below asserts it).
+# is reused for every flush (the recompile counter below asserts it).  The
+# rows run one after another inside the launch: a vmap would put the
+# bucket on the minor axis of every gathered intermediate, which the TPU
+# pads to 128 lanes (on a 2-hop n=100k plan that needs ~34 GB of HBM).
 # _VMANY_ENGINES is the single source of truth for which engines have a
-# vmappable fused executor (sharded plans batch via query_sharded_many
+# batched fused executor (sharded plans batch via query_sharded_many
 # instead — see ShardedSession._exec_group_many).
 _VMANY_ENGINES = ("jax", "jax-iindex")
 _VMANY: Dict[str, object] = {}
 
 
 def _get_vmany(engine: str):
-    # the vmapped executors jit the CHANNEL cores only; finalizers run
-    # eagerly on the [B, n] channel results (same contract as the unbatched
-    # wrappers — inside a jit XLA may FMA-contract a registered finalizer
-    # and re-round, which would make run_many bitwise-diverge from run)
+    # the batched executors jit the CHANNEL cores only; finalizers run on
+    # the host over the [B, n] channel results (same contract as the
+    # unbatched wrappers — inside a jit XLA may FMA-contract a registered
+    # finalizer and re-round, which would make run_many diverge from run)
     if engine not in _VMANY:
         import jax
 
@@ -279,10 +282,10 @@ def _get_vmany(engine: str):
         fn = {"jax": ej._query_dbindex_multi_channels,
               "jax-iindex": ej._query_iindex_multi_channels}[engine]
         _VMANY[engine] = jax.jit(
-            lambda plan, vb, aggs, interpret: jax.vmap(
-                lambda v: fn(plan, v, aggs, use_pallas=False,
-                             interpret=interpret))(vb),
-            static_argnames=("aggs", "interpret"),
+            lambda plan, vb, aggs, use_pallas, interpret: jax.lax.map(
+                lambda v: fn(plan, v, aggs, use_pallas=use_pallas,
+                             interpret=interpret), vb),
+            static_argnames=("aggs", "use_pallas", "interpret"),
         )
     return _VMANY[engine]
 
@@ -816,9 +819,9 @@ class Session:
                         aggs):
         """One [B, n] batch through one materialized window.
 
-        Device plans run the jitted vmapped fused executor (XLA lowering —
-        batching a Pallas kernel is not supported on every backend, and the
-        fused XLA path vmaps cleanly); host engines loop the batch.
+        Device plans run the jitted batched fused executor (the Session's
+        ``use_pallas`` picks the Pallas or the XLA segment-sum, as for
+        :meth:`run`); host engines loop the batch.
         """
         with self.tracer.span("query.term", cat="query", engine=grp.engine,
                               window=window.name(), rows=len(vb)):
@@ -830,13 +833,9 @@ class Session:
                 aggs = tuple(aggs)
                 chans = _get_vmany(grp.engine)(
                     plan, jnp.asarray(vb, jnp.float32), aggs,
-                    self._opts["interpret"],
+                    self._opts["use_pallas"], self._opts["interpret"],
                 )
-                pack = pack_channels(aggs)
-                return {
-                    a: np.asarray(pack.finalize(i, chans, xp=jnp))
-                    for i, a in enumerate(aggs)
-                }
+                return dict(zip(aggs, pack_channels(aggs).finalize(chans)))
             rows = [
                 self.registry.run(grp.engine, g, window, v, aggs,
                                   index=index, plan=plan, **self._opts)
@@ -943,7 +942,7 @@ class Session:
 
     def run_many(self, values_batch) -> List[np.ndarray]:
         """Serving-style traffic: evaluate all specs for a [B, n] batch of
-        attribute vectors in one vmapped launch per device group."""
+        attribute vectors in one batched launch per device group."""
         return self.snapshot().run_many(values_batch)
 
     # ------------------------------------------------------------------ #
@@ -1218,7 +1217,7 @@ class SessionView:
         return out
 
     def run_group_many(self, gi: int, values_batch) -> Dict[str, np.ndarray]:
-        """[B, n] batch through plan group ``gi`` — one vmapped launch per
+        """[B, n] batch through plan group ``gi`` — one batched launch per
         materialized term on device engines (the scheduler's coalesced
         flush path)."""
         with self.session.tracer.span("query.group", cat="query", group=gi,

@@ -25,7 +25,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +36,7 @@ from repro.core.iindex import IIndex
 from repro.kernels.segment_reduce.ops import (
     TilePlan,
     build_tile_plan,
+    keep_shape,
     patch_tile_plan,
     segment_sum,
     segment_sum_gathered,
@@ -89,6 +90,13 @@ class DBIndexPlan:
         nb, p1, p2, bs, lc, e1, e2 = children
         return cls(aux[0], nb, aux[1], p1, p2, bs, lc, e1, e2)
 
+    @property
+    def ell_widths(self) -> Optional[Tuple[int, int]]:
+        """(R1, R2) of the ELL layouts, or None when the plan has none."""
+        if self.p1_ell is None:
+            return None
+        return self.p1_ell.shape[1], self.p2_ell.shape[1]
+
     def array_nbytes(self) -> dict:
         """Exact per-array device bytes, keyed ``pass1.<name>`` /
         ``pass2.<name>`` / top-level array name.  The EXPLAIN footprint
@@ -136,20 +144,33 @@ def _ell_rows(offsets: np.ndarray, items: np.ndarray, num_rows: int,
     return out
 
 
-def _ell_from_index(index: DBIndex, cap: int):
+def _ell_from_index(index: DBIndex, cap: int, prev_widths=None):
     """(p1_ell, p2_ell) for the min/max fast path, or (None, None) when a
     degenerate fan-in distribution would blow the padded layout up (the
-    scatter fallback stays available — min/max are exact either way)."""
+    scatter fallback stays available — min/max are exact either way).
+    ``prev_widths``, the (R1, R2) of a plan being replaced, are kept where
+    :func:`keep_shape` keeps them and the padding rule still holds."""
     max_block = int(np.diff(index.block_offsets).max()) if index.num_blocks else 1
     max_links = int(np.diff(index.link_owner_offsets).max()) if index.n else 1
-    r1, r2 = _pow2(max_block), _pow2(max_links)
-    # the dense reduce beats the XLA scatter until padding inflates the row
-    # count by roughly an order of magnitude (scatter ~50-100ns/row vs ~1-2
-    # ns/element dense); skewed fan-in distributions (one huge block, one
-    # hub owner linking thousands of blocks) fall back to the scatter path
-    if (cap * r1 > max(16 * index.block_members.size, 1 << 16)
-            or index.n * r2 > max(16 * index.link_block.size, 1 << 16)):
+    own = _pow2(max_block), _pow2(max_links)
+
+    def fits(r1, r2):
+        # the dense reduce beats the XLA scatter until padding inflates the
+        # row count by roughly an order of magnitude (scatter ~50-100ns/row
+        # vs ~1-2 ns/element dense); skewed fan-in distributions (one huge
+        # block, one hub owner linking thousands of blocks) fall back to
+        # the scatter path
+        return (cap * r1 <= max(16 * index.block_members.size, 1 << 16)
+                and index.n * r2 <= max(16 * index.link_block.size, 1 << 16))
+
+    widths = own
+    if prev_widths is not None:
+        widths = tuple(keep_shape(p, r, r) for p, r in zip(prev_widths, own))
+        if not fits(*widths):
+            widths = own  # the kept widths pad too far: one retrace
+    if not fits(*widths):
         return None, None
+    r1, r2 = widths
     p1 = _ell_rows(index.block_offsets, index.block_members, cap, r1)
     p2 = _ell_rows(index.link_owner_offsets, index.link_block, index.n, r2)
     return jnp.asarray(p1), jnp.asarray(p2)
@@ -157,15 +178,25 @@ def _ell_from_index(index: DBIndex, cap: int):
 
 def plan_from_dbindex(
     index: DBIndex, tm: int = 512, ts: int = 512,
-    block_capacity: Optional[int] = None, headroom: float = 0.0,
+    headroom: float = 0.0, like=None,
 ) -> DBIndexPlan:
-    cap = max(int(block_capacity or 0), index.num_blocks, 1)
+    """Device plan of ``index``.
+
+    ``like`` is the plan this one replaces (a reorganize, or a rebuild the
+    patcher fell back to), single-host or sharded: its block capacity, tile
+    counts and ELL widths are kept where :func:`keep_shape` keeps them, so
+    a rebuild on a stream does not retrace the jitted queries.
+    """
+    cap = max(index.num_blocks, 1)
     floors = None
     if headroom > 0:
         # pre-pad the block id space to the next power of two past the
         # headroom so streamed secondary-block appends don't change the
         # capacity (and hence the static shapes) on the first few batches
         cap = _pow2(int(cap * (1 + headroom)))
+    if like is not None:
+        cap = keep_shape(like.block_capacity, index.num_blocks, cap)
+    if headroom > 0:
         # appended secondary blocks take consecutive ids just past
         # num_blocks, so the growth lands in a handful of specific tile
         # groups — floor those at the expected rows of a full group of
@@ -176,14 +207,19 @@ def plan_from_dbindex(
         floors = np.ones(n_groups, np.int64)
         g0 = index.num_blocks // ts
         floors[g0: g0 + 4] = max(boost, 1)
+    tiles = (None, None)
+    if isinstance(like, DBIndexPlan):
+        tiles = (like.pass1.seg_tiles.shape[0], like.pass2.seg_tiles.shape[0])
     member_block = np.asarray(index.member_block_ids, np.int64)
     pass1 = build_tile_plan(index.block_members, member_block, cap, tm, ts,
-                            headroom=headroom, group_min_tiles=floors)
+                            headroom=headroom, group_min_tiles=floors,
+                            num_tiles=tiles[0])
     owner_ids = np.asarray(index.link_owner_ids, np.int64)
     pass2 = build_tile_plan(index.link_block, owner_ids, index.n, tm, ts,
-                            headroom=headroom)
+                            headroom=headroom, num_tiles=tiles[1])
     links = np.diff(index.link_owner_offsets).astype(np.float32)
-    p1_ell, p2_ell = _ell_from_index(index, cap)
+    p1_ell, p2_ell = _ell_from_index(
+        index, cap, like.ell_widths if like is not None else None)
     return DBIndexPlan(
         n=index.n,
         num_blocks=index.num_blocks,
@@ -219,12 +255,12 @@ def patch_plan_dbindex(
     stat), the appended-prefix invariant does not hold and splicing would
     silently reuse stale tiles — build a fresh plan instead.
     """
+    if index.stats.get("last_full_rebuild"):
+        return plan_from_dbindex(index, plan.pass1.tm, plan.pass1.ts,
+                                 headroom=headroom, like=plan)
     cap = plan.block_capacity
     if index.num_blocks > cap:
         cap = _pow2(index.num_blocks)
-    if index.stats.get("last_full_rebuild"):
-        return plan_from_dbindex(index, plan.pass1.tm, plan.pass1.ts,
-                                 block_capacity=cap, headroom=headroom)
     member_block = np.asarray(index.member_block_ids, np.int64)
     linked = index.linked_blocks_mask()
     # require actual garbage, not just fraction >= threshold: an empty or
@@ -399,10 +435,10 @@ def _query_dbindex_multi_channels(plan: DBIndexPlan, values, aggs: tuple,
                                   use_pallas: bool = True,
                                   interpret: Optional[bool] = None):
     """Jitted channel core of :func:`query_dbindex_multi`: returns the
-    deduped monoid channel results (finalizers run eagerly in the wrapper —
-    XLA fusion may contract a finalizer's multiply-add into an FMA, which
-    re-rounds; keeping the pure finalize outside the jit keeps registered
-    aggregates bit-identical to their NumPy evaluation)."""
+    deduped monoid channel results (finalizers run on the host in the
+    wrapper — XLA fusion may contract a finalizer's multiply-add into an
+    FMA, which re-rounds, and the TPU's division is not correctly rounded;
+    NumPy keeps registered aggregates bit-identical to the oracle)."""
     from repro.core.aggregates import pack_channels
 
     pack = pack_channels(aggs)
@@ -475,8 +511,7 @@ def query_dbindex_multi(plan: DBIndexPlan, values, aggs: tuple,
     chans = _query_dbindex_multi_channels(plan, values, aggs,
                                           use_pallas=use_pallas,
                                           interpret=interpret)
-    pack = pack_channels(aggs)
-    return tuple(pack.finalize(i, chans, xp=jnp) for i in range(len(aggs)))
+    return pack_channels(aggs).finalize(chans)
 
 
 # the recompile counter the streaming/serving tests assert on lives on the
@@ -655,7 +690,7 @@ def _query_iindex_multi_channels(plan: IIndexPlan, values, aggs: tuple,
                                  use_pallas: bool = True,
                                  interpret: Optional[bool] = None):
     """Jitted channel core of :func:`query_iindex_multi` (finalizers run
-    eagerly in the wrapper — see ``_query_dbindex_multi_channels``)."""
+    on the host in the wrapper — see ``_query_dbindex_multi_channels``)."""
     from repro.core.aggregates import pack_channels
 
     pack = pack_channels(aggs)
@@ -703,8 +738,7 @@ def query_iindex_multi(plan: IIndexPlan, values, aggs: tuple,
                                          schedule=schedule,
                                          use_pallas=use_pallas,
                                          interpret=interpret)
-    pack = pack_channels(aggs)
-    return tuple(pack.finalize(i, chans, xp=jnp) for i in range(len(aggs)))
+    return pack_channels(aggs).finalize(chans)
 
 
 query_iindex_multi._cache_size = _query_iindex_multi_channels._cache_size

@@ -49,7 +49,7 @@ class GraphWindowQuery:
 
 
 def brute_force(g: Graph, window, values: np.ndarray, agg: str = "sum",
-                dtype=None) -> np.ndarray:
+                dtype=None, vertices=None) -> np.ndarray:
     """Reference oracle used by property tests — independent code path.
 
     Per-vertex *set evaluation*: one frontier BFS per leaf, NumPy set ops
@@ -59,6 +59,7 @@ def brute_force(g: Graph, window, values: np.ndarray, agg: str = "sum",
     differentially match a device engine bit-for-bit on integer-valued
     attributes: every partial is an exact integer, so evaluation order is
     irrelevant and the finalizer is the only rounding step on both sides).
+    ``vertices`` evaluates only those owners (results in that order).
     """
     from repro.core.windows import (
         expr_window_single,
@@ -71,8 +72,9 @@ def brute_force(g: Graph, window, values: np.ndarray, agg: str = "sum",
     if dtype is not None:
         chans = tuple(c.astype(dtype) for c in chans)
     idents = [m.identity_for(c.dtype) for m, c in zip(a.monoids, chans)]
-    outs = [np.full(g.n, i, dtype=c.dtype) for i, c in zip(idents, chans)]
-    for v in range(g.n):
+    vs = np.arange(g.n) if vertices is None else np.asarray(vertices)
+    outs = [np.full(vs.size, i, dtype=c.dtype) for i, c in zip(idents, chans)]
+    for j, v in enumerate(vs.tolist()):
         if isinstance(window, KHopWindow):
             w = khop_window_single(g, window.k, v)
         elif isinstance(window, TopologicalWindow):
@@ -80,5 +82,5 @@ def brute_force(g: Graph, window, values: np.ndarray, agg: str = "sum",
         else:
             w = expr_window_single(g, window, v)
         for o, m, c, i in zip(outs, a.monoids, chans, idents):
-            o[v] = m.np_op.reduce(c[w]) if w.size else i
+            o[j] = m.np_op.reduce(c[w]) if w.size else i
     return a.finalize_np(*outs)

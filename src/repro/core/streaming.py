@@ -282,22 +282,33 @@ class StreamingEngine:
 
     # ------------------------------------------------------------------ #
     def _build(self, initial: bool = False) -> None:
-        if self.index_kind == "dbindex":
-            self.index: object = build_dbindex(self.graph, self.window, method=self.method)
-            self._base_links = int(self.index.stats.get("num_links", 0))
-            self._base_blocks = int(self.index.num_blocks)
-        else:
-            self.index = build_iindex(self.graph)
-            self._base_links = self._base_blocks = 0
-        self.plan = None
+        with self.tracer.span("index.build", cat="build",
+                              kind=self.index_kind):
+            if self.index_kind == "dbindex":
+                self.index: object = build_dbindex(self.graph, self.window,
+                                                   method=self.method)
+                self._base_links = int(self.index.stats.get("num_links", 0))
+                self._base_blocks = int(self.index.num_blocks)
+            else:
+                self.index = build_iindex(self.graph)
+                self._base_links = self._base_blocks = 0
+        prev, self.plan = (None if initial else self.plan), None
         if self.device:
+            import jax
+
             from repro.core import engine_jax as ej
 
-            if self.index_kind == "dbindex":
-                self.plan = ej.plan_from_dbindex(self.index, self.tm, self.ts,
-                                                 headroom=self.plan_headroom)
-            else:
-                self.plan = ej.plan_from_iindex(self.index, self.tm, self.ts)
+            # the span ends when the plan is resident on the device
+            with self.tracer.span("plan.upload", cat="build",
+                                  kind=self.index_kind):
+                if self.index_kind == "dbindex":
+                    self.plan = ej.plan_from_dbindex(
+                        self.index, self.tm, self.ts,
+                        headroom=self.plan_headroom, like=prev)
+                else:
+                    self.plan = ej.plan_from_iindex(self.index, self.tm,
+                                                    self.ts)
+                jax.block_until_ready(self.plan)
         self.batches_since_reorg = 0
         if not initial:
             self.reorg_count += 1

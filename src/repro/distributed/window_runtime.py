@@ -97,6 +97,28 @@ def _assign_groups(rows_per_group: np.ndarray, ndev: int):
     return shard_of, offset, max(int(load.max()), 1)
 
 
+def _shard_tiles(tile_plan, ndev: int, prev_rows: Optional[int]):
+    """(tiles per group, rows per shard) of one pass.  A rebuild replacing
+    a plan of ``prev_rows`` rows per shard keeps that count where
+    :func:`keep_shape` keeps it; when the layout's own tiles do not fit in
+    it, each group takes only the tiles its rows fill (the headroom gives
+    way).  A group's rows lead its span, so packing the shorter spans drops
+    padding rows only."""
+    from repro.kernels.segment_reduce.ops import keep_shape
+
+    tiles = _group_layout(tile_plan)[0]
+    rows = _assign_groups(tiles * tile_plan.tm, ndev)[2]
+    if prev_rows is None:
+        return tiles, rows
+    filled = (np.asarray(tile_plan.seg_tiles) >= 0).sum(axis=1)
+    group_rows = np.bincount(np.asarray(tile_plan.m2out), weights=filled,
+                             minlength=tiles.size).astype(np.int64)
+    tight = np.maximum(1, -(-group_rows // tile_plan.tm))
+    need = _assign_groups(tight * tile_plan.tm, ndev)[2]
+    kept = keep_shape(prev_rows, need, rows)
+    return (tight if kept < rows else tiles), kept
+
+
 def _pack_shards(src_seg, src_gather, starts, rows_per_group, shard_of, offset,
                  rows_cap: int, ndev: int):
     """Scatter group row spans into equal per-shard flat arrays (pad -1/0)."""
@@ -161,6 +183,11 @@ class ShardedDBPlan:
     def has_ell(self) -> bool:
         return self.e1 is not None
 
+    @property
+    def ell_widths(self) -> Optional[Tuple[int, int]]:
+        """(R1, R2) of the ELL layouts, or None when the plan has none."""
+        return (self.e1.shape[1], self.e2.shape[1]) if self.has_ell else None
+
     def array_nbytes(self) -> Dict:
         """Exact per-array device bytes — the same accounting surface as
         ``DBIndexPlan.array_nbytes`` / ``IIndexPlan.array_nbytes``, so
@@ -214,7 +241,10 @@ def _shard_put(mesh, axes, arr, sharded: bool):
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    spec = P(axes) if sharded else P()
+    # one entry per dimension: a scatter's output spec is spelled that
+    # way, and a jitted query sees P() and P(None,) as different arguments
+    rest = (None,) * (np.ndim(arr) - 1)
+    spec = P(axes, *rest) if sharded else P(None, *rest)
     return jax.device_put(arr, NamedSharding(mesh, spec))
 
 
@@ -234,19 +264,24 @@ def _ell_shards(rows_np: np.ndarray, num_ids: int, ndev: int):
 
 
 def build_sharded_plan(plan, mesh, axis="data", headroom: float = 0.0,
-                       stats: Optional[Dict] = None) -> ShardedDBPlan:
+                       stats: Optional[Dict] = None,
+                       like: Optional[ShardedDBPlan] = None) -> ShardedDBPlan:
     """Lay a single-host :class:`~repro.core.engine_jax.DBIndexPlan` out as
     device-resident shards (see :class:`ShardedDBPlan`).  ``headroom`` is
     recorded so rebuilds keep the same streaming slack; ``stats`` carries
-    counters forward across rebuilds."""
+    counters forward across rebuilds; ``like``, the plan being replaced,
+    lends its per-shard row counts (see :func:`_shard_tiles`)."""
     axes = _axes_tuple(axis)
     ndev = _mesh_ndev(mesh, axes)
 
-    tiles1, starts1 = _group_layout(plan.pass1)
-    tiles2, starts2 = _group_layout(plan.pass2)
+    tiles1, rows1 = _shard_tiles(plan.pass1, ndev,
+                                 like.rows1 if like is not None else None)
+    tiles2, rows2 = _shard_tiles(plan.pass2, ndev,
+                                 like.rows2 if like is not None else None)
+    starts1, starts2 = _group_layout(plan.pass1)[1], _group_layout(plan.pass2)[1]
     rows_g1, rows_g2 = tiles1 * plan.pass1.tm, tiles2 * plan.pass2.tm
-    shard1, off1, rows1 = _assign_groups(rows_g1, ndev)
-    shard2, off2, rows2 = _assign_groups(rows_g2, ndev)
+    shard1, off1, _ = _assign_groups(rows_g1, ndev)
+    shard2, off2, _ = _assign_groups(rows_g2, ndev)
     p1_seg, p1_gather = _pack_shards(
         np.asarray(plan.pass1.seg_tiles).reshape(-1),
         np.asarray(plan.pass1.gather_padded),
@@ -305,7 +340,6 @@ def build_sharded_plan(plan, mesh, axis="data", headroom: float = 0.0,
 def _sharded_query_impl(sharded, repl, values, mesh, axes, aggs, cfg):
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.aggregates import pack_channels
@@ -412,16 +446,16 @@ def _sharded_query_impl(sharded, repl, values, mesh, axes, aggs, cfg):
         return tuple(outs[ci] for ci in range(len(pack.channels)))
 
     sh = P(axes)
-    fn = shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(tuple(sh for _ in sharded), (P(),), P()),
         out_specs=tuple(P() for _ in pack.channels),
-        check_rep=False,
+        check_vma=False,
     )
-    # channel results only — finalizers run eagerly in the public wrappers
-    # (XLA fusion may FMA-contract a finalizer and re-round; outside the jit
-    # a registered pure finalize matches its NumPy evaluation bit for bit)
+    # channel results only — finalizers run on the host in the public
+    # wrappers (XLA fusion may FMA-contract a finalizer and re-round, and
+    # the TPU's division is not correctly rounded; NumPy matches the oracle)
     return fn(sharded, repl, values)
 
 
@@ -454,12 +488,9 @@ def _splan_call_args(splan: ShardedDBPlan):
 
 
 def _finalize_chans(aggs: tuple, chans):
-    import jax.numpy as jnp
-
     from repro.core.aggregates import pack_channels
 
-    pack = pack_channels(aggs)
-    return tuple(pack.finalize(i, chans, xp=jnp) for i in range(len(aggs)))
+    return pack_channels(aggs).finalize(chans)
 
 
 def query_sharded_multi(splan: ShardedDBPlan, values, aggs: Sequence[str]):
@@ -558,28 +589,18 @@ def patch_sharded_plan(
     message with :func:`apply_wire_message` and lands on a bit-identical
     plan — the patch stream *is* the replication stream.
     """
-    import jax.numpy as jnp
-
     ts = splan.ts
     stats = dict(splan.stats)
     stats["version"] = stats.get("version", 0) + 1
 
     def rebuild():
-        from repro.core.engine_jax import plan_from_dbindex
-
-        cap = splan.block_capacity
-        if index.num_blocks > cap:
-            cap = 1 << (index.num_blocks - 1).bit_length()
-        base = plan_from_dbindex(index, splan.tm, ts, block_capacity=cap,
-                                 headroom=splan.headroom)
         stats["rebuilds"] = stats.get("rebuilds", 0) + 1
         _obs.get_registry().counter(
             "repro_plan_rebuilds_total",
             "sharded plan full rebuilds (recompile-sized events)").inc()
         stats["last_patch_groups"] = -1
         stats["last_compaction"] = False
-        out = build_sharded_plan(base, splan.mesh, splan.axes,
-                                 headroom=splan.headroom, stats=stats)
+        out = _rebuild(index, splan, stats)
         out.stats["last_patch_bytes"] = out.size_bytes()
         if wire is not None:
             from repro.obs.audit import plan_crc
@@ -680,28 +701,10 @@ def patch_sharded_plan(
                         np.concatenate(seg_chunks),
                         np.concatenate(gather_chunks)))
 
-    p1_seg, p1_gather = splan.p1_seg, splan.p1_gather
-    p2_seg, p2_gather = splan.p2_seg, splan.p2_gather
-    for name, pos_np, seg_np, gather_np in patches:
-        pos = jnp.asarray(pos_np)
-        seg_new = jnp.asarray(seg_np)
-        gather_new = jnp.asarray(gather_np)
-        if name == "p1":
-            p1_seg = p1_seg.at[pos].set(seg_new)
-            p1_gather = p1_gather.at[pos].set(gather_new)
-        else:
-            p2_seg = p2_seg.at[pos].set(seg_new)
-            p2_gather = p2_gather.at[pos].set(gather_new)
-
-    block_sizes = splan.block_sizes
     sizes = np.empty(0, np.float32)
     if new_blocks.size:
         sizes = np.diff(index.block_offsets)[new_blocks].astype(np.float32)
-        block_sizes = block_sizes.at[jnp.asarray(new_blocks)].set(
-            jnp.asarray(sizes))
         per_shard += (new_blocks.size * 4) // splan.ndev  # replicated bcast
-
-    e1, e1_ids, e2, e2_ids = splan.e1, splan.e1_ids, splan.e2, splan.e2_ids
     e1_rows = e2_rows = None
     if splan.has_ell:  # widths already validated before the tile scatters
         from repro.core.engine_jax import (
@@ -711,31 +714,27 @@ def patch_sharded_plan(
 
         if new_blocks.size:
             e1_rows = _ell_rows_for_new_blocks(index, splan.num_blocks, r1)
-            e1 = e1.at[jnp.asarray(new_blocks)].set(jnp.asarray(e1_rows))
             rs1 = splan.e1.shape[0] // splan.ndev
             np.add.at(per_shard, (new_blocks // rs1).astype(np.int64),
                       r1 * 4)
         if owners.size:
             e2_rows = _ell_rows_for_owners(index, owners, r2)
-            e2 = e2.at[jnp.asarray(owners)].set(jnp.asarray(e2_rows))
             rs2 = splan.e2.shape[0] // splan.ndev
             np.add.at(per_shard, (owners // rs2).astype(np.int64), r2 * 4)
 
-    if wire is not None:
-        wire.append({
-            "kind": "patch",
-            "num_blocks": int(index.num_blocks),
-            "patches": [(name, pos_np, seg_np, gather_np)
-                        for name, pos_np, seg_np, gather_np in patches],
-            "block_ids": new_blocks,
-            "block_sizes": sizes,
-            "e1_ids": new_blocks if e1_rows is not None
-            else np.empty(0, np.int64),
-            "e1_rows": e1_rows,
-            "e2_ids": owners if e2_rows is not None
-            else np.empty(0, np.int64),
-            "e2_rows": e2_rows,
-        })
+    msg = {
+        "kind": "patch",
+        "num_blocks": int(index.num_blocks),
+        "patches": patches,
+        "block_ids": new_blocks,
+        "block_sizes": sizes,
+        "e1_ids": new_blocks if e1_rows is not None
+        else np.empty(0, np.int64),
+        "e1_rows": e1_rows,
+        "e2_ids": owners if e2_rows is not None
+        else np.empty(0, np.int64),
+        "e2_rows": e2_rows,
+    }
 
     patch_bytes = int(per_shard.sum())
     _obs.get_registry().counter(
@@ -747,22 +746,65 @@ def patch_sharded_plan(
         last_patch_per_shard=per_shard.tolist(),
         patched_bytes_total=stats.get("patched_bytes_total", 0) + patch_bytes,
     )
-    out = dataclasses.replace(
-        splan,
-        num_blocks=index.num_blocks,
-        p1_seg=p1_seg, p1_gather=p1_gather,
-        p2_seg=p2_seg, p2_gather=p2_gather,
-        block_sizes=block_sizes,
-        e1=e1, e1_ids=e1_ids, e2=e2, e2_ids=e2_ids,
-        stats=stats,
-    )
+    out = _apply_patch(splan, msg, stats)
     if wire is not None:
         from repro.obs.audit import plan_crc
 
         # post-apply content digest of the plan this message produces —
         # a follower replaying it self-checks (apply_wire_message)
-        wire[-1]["plan_crc"] = plan_crc(out)
+        msg["plan_crc"] = plan_crc(out)
+        wire.append(msg)
     return out
+
+
+def _rebuild(index: DBIndex, splan: ShardedDBPlan,
+             stats: Dict) -> ShardedDBPlan:
+    """A fresh sharded plan of ``index`` in place of ``splan``, keeping its
+    shapes where the rebuild allows — the leader's rebuild and a
+    follower's resync lay the plan out exactly alike."""
+    from repro.core.engine_jax import plan_from_dbindex
+
+    base = plan_from_dbindex(index, splan.tm, splan.ts,
+                             headroom=splan.headroom, like=splan)
+    return build_sharded_plan(base, splan.mesh, splan.axes,
+                              headroom=splan.headroom, stats=stats, like=splan)
+
+
+def _scatter_rows(arr, ids, rows):
+    """``arr.at[ids].set(rows)`` on a mesh-sharded array.  The result keeps
+    ``arr``'s sharding: a scatter into an explicitly sharded array must
+    name its output sharding."""
+    import jax.numpy as jnp
+
+    return arr.at[jnp.asarray(ids)].set(jnp.asarray(rows),
+                                        out_sharding=arr.sharding)
+
+
+def _apply_patch(splan: ShardedDBPlan, msg: Dict,
+                 stats: Dict) -> ShardedDBPlan:
+    """Scatter one ``"patch"`` message into the device-resident shards —
+    the leader's :func:`patch_sharded_plan` and a follower's
+    :func:`apply_wire_message` run exactly these device updates."""
+    arrays = {"p1_seg": splan.p1_seg, "p1_gather": splan.p1_gather,
+              "p2_seg": splan.p2_seg, "p2_gather": splan.p2_gather}
+    for name, pos_np, seg_np, gather_np in msg["patches"]:
+        arrays[f"{name}_seg"] = _scatter_rows(arrays[f"{name}_seg"], pos_np,
+                                              seg_np)
+        arrays[f"{name}_gather"] = _scatter_rows(arrays[f"{name}_gather"],
+                                                 pos_np, gather_np)
+    block_sizes = splan.block_sizes
+    if msg["block_ids"].size:
+        block_sizes = _scatter_rows(block_sizes, msg["block_ids"],
+                                    msg["block_sizes"])
+    e1, e2 = splan.e1, splan.e2
+    if msg["e1_rows"] is not None and msg["e1_ids"].size:
+        e1 = _scatter_rows(e1, msg["e1_ids"], msg["e1_rows"])
+    if msg["e2_rows"] is not None and msg["e2_ids"].size:
+        e2 = _scatter_rows(e2, msg["e2_ids"], msg["e2_rows"])
+    return dataclasses.replace(
+        splan, num_blocks=int(msg["num_blocks"]), block_sizes=block_sizes,
+        e1=e1, e2=e2, stats=stats, **arrays,
+    )
 
 
 # ---------------------------------------------------------------------- #
@@ -788,60 +830,18 @@ def apply_wire_message(splan: ShardedDBPlan, msg: Dict,
     and ``verify`` is on, the follower recomputes its own plan digest and
     raises :class:`WireDivergenceError` on mismatch — silent follower
     drift is converted into an immediate, attributed failure."""
-    import jax.numpy as jnp
-
     if msg["kind"] == "resync":
-        from repro.core.engine_jax import plan_from_dbindex
-
-        index = msg["index"]
-        cap = splan.block_capacity
-        if index.num_blocks > cap:
-            cap = 1 << (index.num_blocks - 1).bit_length()
-        base = plan_from_dbindex(index, splan.tm, splan.ts,
-                                 block_capacity=cap,
-                                 headroom=splan.headroom)
+        # the leader rebuilt in place of the plan this follower holds
         stats = dict(splan.stats)
         stats["version"] = stats.get("version", 0) + 1
         stats["rebuilds"] = stats.get("rebuilds", 0) + 1
-        out = build_sharded_plan(base, splan.mesh, splan.axes,
-                                 headroom=splan.headroom, stats=stats)
+        out = _rebuild(msg["index"], splan, stats)
         return _verify_wire_crc(out, msg, verify)
 
     assert msg["kind"] == "patch", msg["kind"]
-    p1_seg, p1_gather = splan.p1_seg, splan.p1_gather
-    p2_seg, p2_gather = splan.p2_seg, splan.p2_gather
-    for name, pos_np, seg_np, gather_np in msg["patches"]:
-        pos = jnp.asarray(pos_np)
-        seg_new = jnp.asarray(seg_np)
-        gather_new = jnp.asarray(gather_np)
-        if name == "p1":
-            p1_seg = p1_seg.at[pos].set(seg_new)
-            p1_gather = p1_gather.at[pos].set(gather_new)
-        else:
-            p2_seg = p2_seg.at[pos].set(seg_new)
-            p2_gather = p2_gather.at[pos].set(gather_new)
-    block_sizes = splan.block_sizes
-    if msg["block_ids"].size:
-        block_sizes = block_sizes.at[jnp.asarray(msg["block_ids"])].set(
-            jnp.asarray(msg["block_sizes"]))
-    e1, e2 = splan.e1, splan.e2
-    if msg["e1_rows"] is not None and msg["e1_ids"].size:
-        e1 = e1.at[jnp.asarray(msg["e1_ids"])].set(
-            jnp.asarray(msg["e1_rows"]))
-    if msg["e2_rows"] is not None and msg["e2_ids"].size:
-        e2 = e2.at[jnp.asarray(msg["e2_ids"])].set(
-            jnp.asarray(msg["e2_rows"]))
     stats = dict(splan.stats)
     stats["version"] = stats.get("version", 0) + 1
-    out = dataclasses.replace(
-        splan,
-        num_blocks=int(msg["num_blocks"]),
-        p1_seg=p1_seg, p1_gather=p1_gather,
-        p2_seg=p2_seg, p2_gather=p2_gather,
-        block_sizes=block_sizes,
-        e1=e1, e2=e2,
-        stats=stats,
-    )
+    out = _apply_patch(splan, msg, stats)
     return _verify_wire_crc(out, msg, verify)
 
 
@@ -1017,18 +1017,26 @@ class ShardedStreamState:
         self._build(initial=True)
 
     def _build(self, initial: bool = False) -> None:
+        import jax
+
         from repro.core import engine_jax as ej
 
-        self.index = build_dbindex(self.graph, self.window, method=self.method)
+        with self.tracer.span("index.build", cat="build",
+                              kind=self.index_kind, sharded=True):
+            self.index = build_dbindex(self.graph, self.window,
+                                       method=self.method)
         self._base_links = int(self.index.stats.get("num_links", 0))
         self._base_blocks = int(self.index.num_blocks)
-        base = ej.plan_from_dbindex(self.index, self.tm, self.ts,
-                                    headroom=self.plan_headroom)
-        prev = getattr(self, "plan", None)
-        self.plan = build_sharded_plan(
-            base, self.mesh, self.axes, headroom=self.plan_headroom,
-            stats=prev.stats if prev is not None else None,
-        )
+        prev = None if initial else self.plan
+        with self.tracer.span("plan.upload", cat="build",
+                              kind=self.index_kind, sharded=True):
+            base = ej.plan_from_dbindex(self.index, self.tm, self.ts,
+                                        headroom=self.plan_headroom, like=prev)
+            self.plan = build_sharded_plan(
+                base, self.mesh, self.axes, headroom=self.plan_headroom,
+                stats=prev.stats if prev is not None else None, like=prev,
+            )
+            jax.block_until_ready(self.plan)
         if prev is not None:
             # a reorganize re-uploads the whole plan: the patch telemetry
             # must say so, not echo the previous batch's few-KB patch
@@ -1043,7 +1051,11 @@ class ShardedStreamState:
         if not initial:
             self.reorg_count += 1
             if self.wire_log is not None:
-                self.wire_log.append({"kind": "resync", "index": self.index})
+                from repro.obs.audit import plan_crc
+
+                self.wire_log.append({
+                    "kind": "resync", "index": self.index,
+                    "plan_crc": plan_crc(self.plan)})
 
     # ------------------------------------------------------------------ #
     def _refilter(self, owners: np.ndarray) -> bool:

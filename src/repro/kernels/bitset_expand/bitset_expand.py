@@ -30,7 +30,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 DEFAULT_TM = 256
 DEFAULT_TS = 256
@@ -38,35 +37,43 @@ DEFAULT_TS = 256
 
 def _expand_kernel(m2out_ref, first_ref, seg_ref, rows_ref, base_ref, out_ref, *, ts: int):
     mi = pl.program_id(0)
-    out_tile = m2out_ref[mi]
-    seg = seg_ref[0, :]  # [TM] int32, -1 padding
+    seg_row = seg_ref[...]  # [1, TM] int32, -1 padding
     vals = rows_ref[...].astype(jnp.uint32)  # [TM, W] gathered reach[src]
-    tm = seg.shape[0]
-    vals = jnp.where((seg >= 0)[:, None], vals, jnp.uint32(0))
+    tm, w = vals.shape
+    # seg id of each row replicated across the W lanes, so every row mask
+    # of the scan is a plain [TM, W] elementwise operand
+    seg = jnp.broadcast_to(seg_row, (w, tm)).T
+    row = jax.lax.broadcasted_iota(jnp.int32, (tm, w), 0)
+    vals = jnp.where(seg >= 0, vals, jnp.uint32(0))
     # segmented inclusive OR-scan down the rows
     shift = 1
     while shift < tm:
         rolled = pltpu.roll(vals, shift, 0)
         seg_rolled = pltpu.roll(seg, shift, 0)
-        row = jax.lax.broadcasted_iota(jnp.int32, (tm,), 0)
         same = (row >= shift) & (seg_rolled == seg)
-        vals = vals | jnp.where(same[:, None], rolled, jnp.uint32(0))
+        vals = vals | jnp.where(same, rolled, jnp.uint32(0))
         shift *= 2
-    # boundary = last row of each segment within the tile
-    nxt = pltpu.roll(seg, tm - 1, 0)  # nxt[i] = seg[i+1 mod tm]
-    row = jax.lax.broadcasted_iota(jnp.int32, (tm,), 0)
-    boundary = (seg >= 0) & ((nxt != seg) | (row == tm - 1))
-    rel = jnp.where(boundary, seg - out_tile * ts, 0)
-    ok = boundary & (rel >= 0) & (rel < ts)
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tm, ts), 1)
-    oh = jnp.where(ok[:, None], (iota == rel[:, None]).astype(jnp.float32), 0.0)
-    lo = (vals & jnp.uint32(0xFFFF)).astype(jnp.float32)
-    hi = (vals >> jnp.uint32(16)).astype(jnp.float32)
-    plo = jax.lax.dot_general(oh, lo, (((0,), (0,)), ((), ())),
+    # boundary = last row of each segment within the tile, found on the row
+    nxt = pltpu.roll(seg_row, tm - 1, 1)  # nxt[i] = seg[i+1 mod tm]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tm), 1)
+    boundary = (seg_row >= 0) & ((nxt != seg_row) | (lane == tm - 1))
+    # transposed one-hot [TS, TM]: non-boundary rows and rows outside this
+    # output tile match no iota value
+    rel = jnp.where(boundary, seg_row - m2out_ref[mi] * ts, -1)
+    oh_t = (jax.lax.broadcasted_iota(jnp.int32, (ts, tm), 0) == rel).astype(
+        jnp.float32)
+    # the TPU has no direct uint32 <-> f32 cast; halves < 2^16 fit int32.
+    # HIGHEST keeps the halves exact through the MXU (a one-pass bf16
+    # matmul would round any half above 256)
+    lo = (vals & jnp.uint32(0xFFFF)).astype(jnp.int32).astype(jnp.float32)
+    hi = (vals >> jnp.uint32(16)).astype(jnp.int32).astype(jnp.float32)
+    dims = (((1,), (0,)), ((), ()))
+    plo = jax.lax.dot_general(oh_t, lo, dims, precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
-    phi = jax.lax.dot_general(oh, hi, (((0,), (0,)), ((), ())),
+    phi = jax.lax.dot_general(oh_t, hi, dims, precision=jax.lax.Precision.HIGHEST,
                               preferred_element_type=jnp.float32)
-    partial = plo.astype(jnp.uint32) | (phi.astype(jnp.uint32) << jnp.uint32(16))
+    partial = (plo.astype(jnp.int32).astype(jnp.uint32)
+               | (phi.astype(jnp.int32).astype(jnp.uint32) << jnp.uint32(16)))
 
     @pl.when(first_ref[mi] == 1)
     def _init():
@@ -96,7 +103,9 @@ def bitset_expand_tiled(
         num_scalar_prefetch=2,
         grid=(num_m_tiles,),
         in_specs=[
-            pl.BlockSpec((1, tm), lambda mi, m2out, first: (mi, 0)),
+            # one [1, TM] seg-id row per tile: the last two block dims equal
+            # the array's, as the TPU lowering requires
+            pl.BlockSpec((None, 1, tm), lambda mi, m2out, first: (mi, 0, 0)),
             pl.BlockSpec((tm, w), lambda mi, m2out, first: (mi, 0)),
             pl.BlockSpec((ts, w), lambda mi, m2out, first: (m2out[mi], 0)),
         ],
@@ -106,6 +115,6 @@ def bitset_expand_tiled(
         functools.partial(_expand_kernel, ts=ts),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((num_out_tiles * ts, w), jnp.uint32),
-        compiler_params=_CompilerParams(dimension_semantics=(pltpu.ARBITRARY,)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(pltpu.ARBITRARY,)),
         interpret=interpret,
-    )(m2out, first_visit, seg_ids, gathered_rows, base)
+    )(m2out, first_visit, seg_ids.reshape(num_m_tiles, 1, tm), gathered_rows, base)

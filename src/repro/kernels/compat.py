@@ -1,17 +1,6 @@
-"""Pallas API drift shims shared by all kernels.
-
-jax >= 0.5 renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams``;
-the toolchain image pins 0.4.x.  Keep every version-compatibility alias —
-and every other per-kernel copy-pasted default, like the off-TPU interpret
-fallback — here so a toolchain upgrade is a one-file change (ROADMAP open
-item).
-"""
+"""Defaults shared by every Pallas kernel's ops wrapper."""
 
 from __future__ import annotations
-
-from jax.experimental.pallas import tpu as pltpu
-
-CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
 
 
 def default_interpret() -> bool:

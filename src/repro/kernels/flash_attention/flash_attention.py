@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 DEFAULT_BQ = 512
 DEFAULT_BK = 512
@@ -106,7 +105,7 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(
                 pltpu.PARALLEL, pltpu.PARALLEL, pltpu.PARALLEL, pltpu.ARBITRARY,
             )

@@ -15,7 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 DEFAULT_TB = 256
 
@@ -42,7 +41,7 @@ def fm_interaction(emb, tb: int = DEFAULT_TB, interpret: bool = False):
         in_specs=[pl.BlockSpec((tb, f, emb.shape[2]), lambda i: (i, 0, 0))],
         out_specs=pl.BlockSpec((tb, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((bb, 1), jnp.float32),
-        compiler_params=_CompilerParams(dimension_semantics=(pltpu.PARALLEL,)),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=(pltpu.PARALLEL,)),
         interpret=interpret,
     )(emb.astype(jnp.float32))
     return out[:b, 0]

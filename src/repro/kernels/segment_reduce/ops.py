@@ -70,6 +70,23 @@ jax.tree_util.register_pytree_node(
     TilePlan, TilePlan.tree_flatten, TilePlan.tree_unflatten
 )
 
+# A rebuilt plan keeps each static size of the plan it replaces, so the
+# jitted queries do not retrace, unless that size would pad the rebuild to
+# more than this many times its own; it then takes its own size and the
+# queries retrace once.
+KEEP_SHAPE_MAX_PAD = 2
+
+
+def keep_shape(prev: Optional[int], need: int, own: int) -> int:
+    """One static size of a rebuilt plan: ``prev``, the replaced plan's,
+    when the rebuild's content (``need``) fits in it and it is at most
+    ``KEEP_SHAPE_MAX_PAD`` times the rebuild's own size ``own``; else
+    ``own``.  The single-host and the sharded rebuilds size every kept
+    dimension (block capacity, tiles, ELL widths, rows per shard) here."""
+    if prev is not None and need <= prev <= KEEP_SHAPE_MAX_PAD * own:
+        return int(prev)
+    return int(own)
+
 
 def build_tile_plan(
     gather_idx: np.ndarray,
@@ -79,6 +96,7 @@ def build_tile_plan(
     ts: int = DEFAULT_TS,
     headroom: float = 0.0,
     group_min_tiles: "Optional[np.ndarray]" = None,
+    num_tiles: Optional[int] = None,
 ) -> TilePlan:
     """Host-side plan: rows (sorted by segment id) -> tile-aligned layout.
 
@@ -89,6 +107,10 @@ def build_tile_plan(
     recompile-free — until the cumulative growth exceeds the slack.
     ``group_min_tiles`` optionally floors individual groups' tile counts —
     the caller's way to concentrate slack where appends will land.
+    ``num_tiles``, the input tile count of a plan this one replaces, is
+    kept where :func:`keep_shape` keeps it (slack first gives way, then
+    spare tiles are spread over the groups), so the jitted consumers do
+    not retrace.
     """
     gather_idx = np.asarray(gather_idx, np.int32)
     segment_ids = np.asarray(segment_ids, np.int64)
@@ -101,7 +123,8 @@ def build_tile_plan(
     if group_rows.size < n_out_tiles:
         group_rows = np.pad(group_rows, (0, n_out_tiles - group_rows.size))
     # >=1 input tile per output tile so every output block gets initialized
-    tiles_per_group = np.maximum(1, -(-group_rows // tm))
+    needed = np.maximum(1, -(-group_rows // tm))
+    tiles_per_group = needed
     if headroom > 0:
         extra = max(1, -(-int(group_rows.sum() * headroom) // (n_out_tiles * tm)))
         tiles_per_group = tiles_per_group + extra
@@ -109,6 +132,15 @@ def build_tile_plan(
         tiles_per_group = np.maximum(
             tiles_per_group, group_min_tiles[:n_out_tiles].astype(np.int64)
         )
+    if num_tiles is not None:
+        num_tiles = keep_shape(num_tiles, int(needed.sum()),
+                               int(tiles_per_group.sum()))
+        if tiles_per_group.sum() > num_tiles:
+            tiles_per_group = needed  # the rows fit, the slack does not
+        spare = num_tiles - int(tiles_per_group.sum())
+        if spare > 0:  # spread evenly, as headroom is
+            tiles_per_group = tiles_per_group + spare // n_out_tiles
+            tiles_per_group[-1] += spare % n_out_tiles
     padded_rows = tiles_per_group * tm
     total_pad = int(padded_rows.sum())
     nm = int(tiles_per_group.sum())
@@ -283,17 +315,10 @@ def segment_sum_gathered(
     interpret = _default_interpret() if interpret is None else interpret
     squeeze = gathered.ndim == 1
     v = gathered[:, None] if squeeze else gathered
-    d = v.shape[1]
     if use_pallas:
-        # the MXU kernel wants 128-lane tiles; the XLA fallback does not —
-        # padding there would do 128/d times the useful work
-        pad_d = (-d) % 128
-        if pad_d:
-            v = jnp.pad(v, ((0, 0), (0, pad_d)))
-    gathered = v
-    if use_pallas:
+        # the kernel takes channels-major rows ([D, Mpad]: rows on lanes)
         out = segment_sum_tiled(
-            gathered.astype(jnp.float32),
+            v.astype(jnp.float32).T,
             plan.seg_tiles,
             plan.m2out,
             plan.first_visit,
@@ -301,16 +326,16 @@ def segment_sum_gathered(
             tm=plan.tm,
             ts=plan.ts,
             interpret=interpret,
-        )
+        ).T
     else:  # XLA fallback (same tile-aligned inputs)
         sid = plan.seg_tiles.reshape(-1)
         ok = sid >= 0
         out = jax.ops.segment_sum(
-            jnp.where(ok[:, None], gathered, 0).astype(jnp.float32),
+            jnp.where(ok[:, None], v, 0).astype(jnp.float32),
             jnp.where(ok, sid, plan.num_out_tiles * plan.ts),
             num_segments=plan.num_out_tiles * plan.ts + 1,
         )[:-1]
-    out = out[: plan.num_segments, :d]
+    out = out[: plan.num_segments]
     return out[:, 0] if squeeze else out
 
 

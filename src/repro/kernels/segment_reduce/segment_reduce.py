@@ -9,15 +9,18 @@ renumbers segments and pads rows so that
 * all tiles visiting one output tile are consecutive in the grid.
 
 Inside the kernel, the per-tile reduction becomes a one-hot matmul on the
-MXU: ``partial[TS, D] = one_hot(seg - ts0)^T @ vals`` — the scatter that a
-GPU implementation would do with atomics is a systolic matrix product here.
+MXU: ``partial[D, TS] = vals[D, TM] @ one_hot(seg - ts0)`` — the scatter
+that a GPU implementation would do with atomics is a systolic matrix product
+here.  Rows run along the lanes (channels-major ``[D, M]`` operands), so a
+one- or two-channel query streams ``D`` sublanes per row instead of padding
+every row out to 128 lanes in HBM.
 Revisit accumulation relies on Pallas TPU semantics: an output block whose
 index_map repeats across *consecutive* grid steps stays resident in VMEM, so
 ``out += partial`` accumulates without ever round-tripping HBM.
 
-VMEM budget per grid step (defaults ``TM=512, TS=512, D<=256`` f32):
-vals 512·256·4 = 512 KiB, one-hot 512·512·4 = 1 MiB, out 512 KiB — well
-under the ~16 MiB/core budget, MXU-aligned (multiples of 128).
+VMEM budget per grid step (defaults ``TM=512, TS=512``, f32): vals
+``D``·512·4 B, one-hot 512·512·4 = 1 MiB, out ``D``·512·4 B — well under
+the ~16 MiB/core budget, MXU-aligned (multiples of 128).
 """
 
 from __future__ import annotations
@@ -29,7 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams as _CompilerParams
 
 DEFAULT_TM = 512  # rows per input tile
 DEFAULT_TS = 512  # segment ids per output tile
@@ -37,20 +39,21 @@ DEFAULT_TS = 512  # segment ids per output tile
 
 def _seg_sum_kernel(m2out_ref, first_ref, seg_ref, vals_ref, out_ref, *, ts: int):
     mi = pl.program_id(0)
-    out_tile = m2out_ref[mi]
-    seg = seg_ref[0, :]  # [TM] int32 (padding rows carry -1)
-    vals = vals_ref[...]  # [TM, D]
-    tm = seg.shape[0]
-    rel = seg - out_tile * ts
-    valid = (rel >= 0) & (rel < ts)
-    rel = jnp.where(valid, rel, 0)
-    # one-hot [TM, TS] on the fly; padding rows masked out
-    iota = jax.lax.broadcasted_iota(jnp.int32, (tm, ts), 1)
-    oh = jnp.where(valid[:, None], (iota == rel[:, None]).astype(vals.dtype), 0)
+    seg = seg_ref[...]  # [1, TM] int32 (padding rows carry -1)
+    vals = vals_ref[...]  # [D, TM]
+    tm = seg.shape[1]
+    rel = seg - m2out_ref[mi] * ts
+    # transposed one-hot [TS, TM] built on the fly from the seg-id row: a
+    # padding row or a row outside this output tile matches no iota value
+    iota = jax.lax.broadcasted_iota(jnp.int32, (ts, tm), 0)
+    oh_t = (iota == rel).astype(vals.dtype)
+    # HIGHEST keeps the f32 values exact through the MXU (a one-pass bf16
+    # matmul would round them to 8 mantissa bits)
     partial = jax.lax.dot_general(
-        oh,
         vals,
-        (((0,), (0,)), ((), ())),  # contract over TM: [TS, D]
+        oh_t,
+        (((1,), (1,)), ((), ())),  # contract over TM: [D, TS]
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
 
@@ -67,7 +70,7 @@ def _seg_sum_kernel(m2out_ref, first_ref, seg_ref, vals_ref, out_ref, *, ts: int
     jax.jit, static_argnames=("num_out_tiles", "tm", "ts", "interpret")
 )
 def segment_sum_tiled(
-    vals,  # [M_pad, D] pre-gathered rows, grouped by segment
+    vals,  # [D, M_pad] pre-gathered rows (channels-major), grouped by segment
     seg_ids,  # [num_m_tiles, TM] int32, -1 on padding rows
     m2out,  # [num_m_tiles] int32: output tile per input tile (non-decreasing)
     first_visit,  # [num_m_tiles] int32 {0,1}
@@ -77,25 +80,27 @@ def segment_sum_tiled(
     ts: int = DEFAULT_TS,
     interpret: bool = False,
 ):
-    """Returns [num_out_tiles * TS, D] f32 segment sums."""
+    """Returns [D, num_out_tiles * TS] f32 segment sums."""
     num_m_tiles = seg_ids.shape[0]
-    d = vals.shape[1]
-    assert vals.shape[0] == num_m_tiles * tm, (vals.shape, num_m_tiles, tm)
+    d = vals.shape[0]
+    assert vals.shape[1] == num_m_tiles * tm, (vals.shape, num_m_tiles, tm)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,  # m2out, first_visit
         grid=(num_m_tiles,),
         in_specs=[
-            pl.BlockSpec((1, tm), lambda mi, m2out, first: (mi, 0)),
-            pl.BlockSpec((tm, d), lambda mi, m2out, first: (mi, 0)),
+            # one [1, TM] seg-id row per tile: the last two block dims equal
+            # the array's, as the TPU lowering requires
+            pl.BlockSpec((None, 1, tm), lambda mi, m2out, first: (mi, 0, 0)),
+            pl.BlockSpec((d, tm), lambda mi, m2out, first: (0, mi)),
         ],
-        out_specs=pl.BlockSpec((ts, d), lambda mi, m2out, first: (m2out[mi], 0)),
+        out_specs=pl.BlockSpec((d, ts), lambda mi, m2out, first: (0, m2out[mi])),
     )
     return pl.pallas_call(
         functools.partial(_seg_sum_kernel, ts=ts),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((num_out_tiles * ts, d), jnp.float32),
-        compiler_params=_CompilerParams(
+        out_shape=jax.ShapeDtypeStruct((d, num_out_tiles * ts), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=(pltpu.ARBITRARY,)
         ),
         interpret=interpret,
-    )(m2out, first_visit, seg_ids, vals)
+    )(m2out, first_visit, seg_ids.reshape(num_m_tiles, 1, tm), vals)

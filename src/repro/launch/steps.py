@@ -429,8 +429,6 @@ def build_gwq_step(plan_dims: Dict[str, int], mesh) -> BuiltStep:
         """Blocks/owners co-located with their rows (MinHash clusters are
         locality groups): pass-1/pass-2 segment sums run shard-locally
         under shard_map; only the 1/bf boundary slices are psum'd."""
-        from jax.experimental.shard_map import shard_map
-
         nb_b = nb // bf
         n_b = n // bf
         nb_loc = nb - nb_b
@@ -455,10 +453,10 @@ def build_gwq_step(plan_dims: Dict[str, int], mesh) -> BuiltStep:
             out_boundary = jax.lax.psum(out_all[n_loc:], axes)
             return jnp.concatenate([out_all[:n_loc], out_boundary])
 
-        fn = shard_map(
+        fn = jax.shard_map(
             local, mesh=mesh,
             in_specs=(P(axes), P(axes), P(axes), P(axes), P()),
-            out_specs=P(), check_rep=False,
+            out_specs=P(), check_vma=False,
         )
         return fn(p1g, p1s, p2g, p2s, vals)
 

@@ -202,7 +202,6 @@ def moe_ffn(lp, x, cfg: MoEConfig, acts=None):
         return out.reshape(b, s, d), jnp.mean(aux)
 
     mesh, token_axes, tp = moe_shard
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(xt_l, router, wg, wu, wd, *shared_l):
@@ -228,7 +227,7 @@ def moe_ffn(lp, x, cfg: MoEConfig, acts=None):
     shared_specs = tuple(
         [P(None, tp), P(None, tp), P(tp, None)]
     ) if shared is not None else ()
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(
@@ -240,7 +239,7 @@ def moe_ffn(lp, x, cfg: MoEConfig, acts=None):
             *shared_specs,
         ),
         out_specs=(P(token_axes, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(xt, lp["router"], lp["we_gate"], lp["we_up"], lp["we_down"], *shared_args)
     return out.reshape(b, s, d), aux
 

@@ -173,8 +173,7 @@ def _analyze_dbindex_term(clock: _PhaseClock, gi: int, tname: str, plan,
     chans = tuple(outs[ci] for ci in range(len(pack.channels)))
     return clock.timed(
         gi, tname, "finalize",
-        lambda: {a: np.asarray(pack.finalize(i, chans, xp=jnp))
-                 for i, a in enumerate(aggs)})
+        lambda: dict(zip(aggs, pack.finalize(chans))))
 
 
 def _analyze_iindex_term(clock: _PhaseClock, gi: int, tname: str, plan,
@@ -239,8 +238,7 @@ def _analyze_iindex_term(clock: _PhaseClock, gi: int, tname: str, plan,
     clock.timed(gi, tname, "inherit", _inherit)
     return clock.timed(
         gi, tname, "finalize",
-        lambda: {a: np.asarray(pack.finalize(i, tuple(chans), xp=jnp))
-                 for i, a in enumerate(aggs)})
+        lambda: dict(zip(aggs, pack.finalize(chans))))
 
 
 # ---------------------------------------------------------------------- #

@@ -11,7 +11,7 @@ runtime) with three mechanisms:
   submit` and :meth:`~WindowService.flush` coalesces them per (window,
   attr) plan group into padded ``run_many`` launches at a fixed batch
   bucket.  Same scale posture as :class:`repro.serve.engine.ServeEngine`'s
-  slot design: the [bucket, n] batch never reshapes, so the vmapped fused
+  slot design: the [bucket, n] batch never reshapes, so the batched fused
   executable compiles once and every flush replays it (zero retraces —
   ``repro.core.api.run_many_cache_size`` is the counter).
 

@@ -202,6 +202,76 @@ def test_session_update_query_roundtrip_no_recompile():
     assert sess.updates_applied == 20
 
 
+@pytest.mark.parametrize("sharded", [False, True])
+def test_session_reorganize_keeps_plan_shapes(sharded):
+    """2-hop batches trip the staleness policy (the merged secondary blocks
+    share little), so the stream reorganizes; each rebuilt plan keeps the
+    shapes of the one it replaces and the fused query never retraces."""
+    g = with_random_attrs(erdos_renyi(1500, 6.0, directed=False, seed=21),
+                          seed=22)
+    specs = [QuerySpec(("khop", 2), a) for a in ("sum", "min")]
+    mesh = jax.make_mesh((1,), ("data",)) if sharded else None
+    sess = Session(g, specs, device=True, use_pallas=False, mesh=mesh)
+    sess.run()
+    cache0 = recompile_count()
+    rng = np.random.default_rng(23)
+    reorganized = 0
+    for _ in range(4):
+        reports = sess.update(mixed(sess.graph, rng, 20, 10))
+        reorganized += any(r["reorganized"] for r in reports.values())
+        vals = sess.graph.attrs["val"]
+        for s, r in zip(specs, sess.run()):
+            ref = brute_force(sess.graph, s.window, vals, s.agg,
+                              dtype=np.float32)
+            assert np.array_equal(r, np.asarray(ref, r.dtype)), s.agg
+    assert reorganized >= 2
+    assert recompile_count() == cache0
+
+
+@pytest.mark.parametrize("sharded", [False, True])
+def test_session_reorganize_shrinks_plan_when_widest_block_shrinks(sharded):
+    """A hub's wide block sets the ELL width R1; once the hub's edges are
+    deleted, the reorganized index's own R1 is a quarter of it.  Keeping
+    the old width would pad the min/max layout past ``keep_shape``'s
+    bound, so the rebuild takes its own widths (one retrace) and pads no
+    more than a fresh plan of the same index."""
+    from repro.core.streaming import StalenessPolicy
+    from repro.core.updates import UpdateBatch, apply_batch
+    from repro.kernels.segment_reduce.ops import KEEP_SHAPE_MAX_PAD
+
+    g = with_random_attrs(erdos_renyi(600, 3.0, directed=False, seed=5),
+                          seed=6)
+    hub_s, hub_d = np.zeros(50, np.int32), np.arange(1, 51, dtype=np.int32)
+    fresh = ~g.contains_edges(hub_s, hub_d)
+    hub = UpdateBatch.inserts(hub_s[fresh], hub_d[fresh])
+    g = apply_batch(g, hub)
+    specs = [QuerySpec(("khop", 1), a) for a in ("sum", "min")]
+    mesh = jax.make_mesh((1,), ("data",)) if sharded else None
+    sess = Session(g, specs, device=True, use_pallas=False, mesh=mesh,
+                   policy=StalenessPolicy(max_link_ratio=0.0))
+
+    def widths_and_padding():
+        (index, plan), = sess._group_artifacts(0)
+        r1, r2 = plan.ell_widths
+        return (r1, r2), plan.block_capacity * r1 / index.block_members.size
+
+    (wide_r1, _), _ = widths_and_padding()
+    reports = sess.update(UpdateBatch.deletes(hub.src, hub.dst))
+    assert all(r["reorganized"] for r in reports.values())
+    (index, _), = sess._group_artifacts(0)
+    own = ej.plan_from_dbindex(index, headroom=0.5)
+    own_padding = own.block_capacity * own.ell_widths[0] \
+        / index.block_members.size
+    widths, padding = widths_and_padding()
+    assert wide_r1 > KEEP_SHAPE_MAX_PAD * own.ell_widths[0]  # the premise
+    assert widths == own.ell_widths
+    assert padding <= KEEP_SHAPE_MAX_PAD * own_padding
+    vals = sess.graph.attrs["val"]
+    for s, r in zip(specs, sess.run()):
+        ref = brute_force(sess.graph, s.window, vals, s.agg, dtype=np.float32)
+        assert np.array_equal(r, np.asarray(ref, r.dtype)), s.agg
+
+
 def test_session_mixed_windows_and_attrs(topo_case):
     g, w, refs = topo_case
     g = g.with_attr("weight", np.arange(g.n, dtype=np.float64))

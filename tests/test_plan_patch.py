@@ -143,7 +143,7 @@ def test_dbindex_patched_plan_parity(k, directed):
         idx, owners = U.update_dbindex_batch(idx, g, w, b)
         plan = ej.patch_plan_dbindex(plan, idx, owners)
         fresh = ej.plan_from_dbindex(idx, tm=64, ts=64,
-                                     block_capacity=plan.block_capacity)
+                                     like=plan)
         for agg in ("sum", "count", "avg"):
             got = np.asarray(ej.query_dbindex(plan, g.attrs["val"], agg,
                                               use_pallas=False))
@@ -265,7 +265,7 @@ def test_dbindex_large_affected_set_falls_back_and_plan_stays_valid():
                                       use_pallas=False))
     fresh = np.asarray(ej.query_dbindex(
         ej.plan_from_dbindex(idx2, tm=64, ts=64,
-                             block_capacity=plan2.block_capacity),
+                             like=plan2),
         g2.attrs["val"], "sum", use_pallas=False))
     assert np.array_equal(got, fresh)
     oracle = brute_force(g2, w, g2.attrs["val"], "sum")
