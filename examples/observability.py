@@ -19,9 +19,13 @@ while three request classes compete, then reads everything back out:
 * a Prometheus text exposition;
 * a Chrome `trace_event` JSON (load it at chrome://tracing or
   https://ui.perfetto.dev) with the full span hierarchy:
-  flush > launch > query.group > query.term on the read path and
-  service.update > session.update > maintain > index.update/plan.patch
-  on the write path, plus one detached "request" span per ticket.
+  flush > launch > query.group > executor.device/executor.finalize on
+  the read path (query.term sits between the last two only for the terms
+  of a composite window) and service.update > session.update > maintain
+  > index.update/plan.patch on the write path, plus one detached
+  "request" span per ticket (its queue wait in ``queued_ms``, linked to
+  the flush that served it), the flusher's ``flush.wait`` and one root
+  ``gc`` span per garbage collection.
 
 Reading the metrics
 -------------------

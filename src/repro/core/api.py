@@ -808,12 +808,10 @@ class Session:
     # ------------------------------------------------------------------ #
     def _exec_term(self, grp: PlanGroup, window, index, plan, values, g,
                    aggs):
-        with self.tracer.span("query.term", cat="query",
-                              engine=grp.engine, window=window.name()):
-            return self.registry.run(
-                grp.engine, g, window, values, aggs,
-                index=index, plan=plan, **self._opts,
-            )
+        return self.registry.run(
+            grp.engine, g, window, values, aggs,
+            index=index, plan=plan, **self._opts,
+        )
 
     def _exec_term_many(self, grp: PlanGroup, window, index, plan, vb, g,
                         aggs):
@@ -821,27 +819,33 @@ class Session:
 
         Device plans run the jitted batched fused executor (the Session's
         ``use_pallas`` picks the Pallas or the XLA segment-sum, as for
-        :meth:`run`); host engines loop the batch.
+        :meth:`run`); host engines loop the batch.  On the device path
+        ``executor.device`` spans the launch until its channels are ready
+        and ``executor.finalize`` their fetch and the host finalizers.
         """
-        with self.tracer.span("query.term", cat="query", engine=grp.engine,
-                              window=window.name(), rows=len(vb)):
-            if plan is not None and grp.engine in _VMANY_ENGINES:
-                import jax.numpy as jnp
+        if plan is not None and grp.engine in _VMANY_ENGINES:
+            import jax
+            import jax.numpy as jnp
 
-                from repro.core.aggregates import pack_channels
+            from repro.core.aggregates import pack_channels
 
-                aggs = tuple(aggs)
+            aggs = tuple(aggs)
+            with self.tracer.span("executor.device", cat="query",
+                                  rows=len(vb)):
                 chans = _get_vmany(grp.engine)(
                     plan, jnp.asarray(vb, jnp.float32), aggs,
                     self._opts["use_pallas"], self._opts["interpret"],
                 )
+                # the fetch below would wait here anyway
+                jax.block_until_ready(chans)
+            with self.tracer.span("executor.finalize", cat="query"):
                 return dict(zip(aggs, pack_channels(aggs).finalize(chans)))
-            rows = [
-                self.registry.run(grp.engine, g, window, v, aggs,
-                                  index=index, plan=plan, **self._opts)
-                for v in vb
-            ]
-            return {a: np.stack([r[a] for r in rows]) for a in aggs}
+        rows = [
+            self.registry.run(grp.engine, g, window, v, aggs,
+                              index=index, plan=plan, **self._opts)
+            for v in vb
+        ]
+        return {a: np.stack([r[a] for r in rows]) for a in aggs}
 
     def _exec_group(self, gi: int, arts, values, graph=None):
         grp = self.compiled.groups[gi]
@@ -852,10 +856,11 @@ class Session:
             index, plan = arts[0]
             return self._exec_term(grp, grp.window, index, plan, vals, g,
                                    grp.aggs)
-        term_outs = [
-            self._exec_term(grp, term, index, plan, vals, g, prog.term_aggs)
-            for term, (index, plan) in zip(prog.terms, arts)
-        ]
+        term_outs = []
+        for term, (index, plan) in zip(prog.terms, arts):
+            with self._term_span(grp, term):
+                term_outs.append(self._exec_term(
+                    grp, term, index, plan, vals, g, prog.term_aggs))
         return _combine_program(prog, grp.aggs, term_outs)
 
     def _exec_group_many(self, gi: int, arts, vb, graph=None):
@@ -866,12 +871,18 @@ class Session:
             index, plan = arts[0]
             return self._exec_term_many(grp, grp.window, index, plan, vb, g,
                                         grp.aggs)
-        term_outs = [
-            self._exec_term_many(grp, term, index, plan, vb, g,
-                                 prog.term_aggs)
-            for term, (index, plan) in zip(prog.terms, arts)
-        ]
+        term_outs = []
+        for term, (index, plan) in zip(prog.terms, arts):
+            with self._term_span(grp, term, rows=len(vb)):
+                term_outs.append(self._exec_term_many(
+                    grp, term, index, plan, vb, g, prog.term_aggs))
         return _combine_program(prog, grp.aggs, term_outs)
+
+    def _term_span(self, grp: PlanGroup, term, **args):
+        """``query.term``: one algebraic term of a composite program (a
+        single-term group's ``query.group`` already covers its query)."""
+        return self.tracer.span("query.term", cat="query", engine=grp.engine,
+                                window=term.name(), **args)
 
     # ------------------------------------------------------------------ #
     #  Versioned snapshot reads + result cache hooks

@@ -40,6 +40,7 @@ from repro.kernels.segment_reduce.ops import (
     patch_tile_plan,
     segment_sum,
     segment_sum_gathered,
+    set_rows,
 )
 
 
@@ -350,12 +351,12 @@ def _patch_ell(plan: DBIndexPlan, index: DBIndex, cap: int,
     p1_ell = plan.p1_ell
     if new_sizes.size:
         rows = _ell_rows_for_new_blocks(index, plan.num_blocks, r1)
-        ids = jnp.asarray(np.arange(plan.num_blocks, index.num_blocks))
-        p1_ell = p1_ell.at[ids].set(jnp.asarray(rows))
+        ids = np.arange(plan.num_blocks, index.num_blocks)
+        p1_ell = set_rows(p1_ell, ids, rows)
     p2_ell = plan.p2_ell
     if owners.size:
         rows = _ell_rows_for_owners(index, owners, r2)
-        p2_ell = p2_ell.at[jnp.asarray(owners)].set(jnp.asarray(rows))
+        p2_ell = set_rows(p2_ell, owners, rows)
     return p1_ell, p2_ell
 
 
@@ -450,42 +451,53 @@ def _query_dbindex_multi_channels(plan: DBIndexPlan, values, aggs: tuple,
 
     # ---- pass 1: one shared gather of the attribute vector -------------- #
     # registered derived aggregates add "square" channels; they reuse the
-    # same gather (take(v², idx) == take(v, idx)² elementwise)
-    need_g1 = any(
-        pack.channels[ci][1] in ("value", "square") for ci in sum_cols
-    ) or (plan.p1_ell is None and minmax_cols)
-    g1 = jnp.take(values, plan.pass1.gather_padded) if need_g1 else None
+    # same gather (take(v², idx) == take(v, idx)² elementwise).  Each phase
+    # runs under a named scope (metadata only) that the device trace shows
+    # as its operations' op_name; the shared gather counts to the sums
+    # unless only the masked min/max need it.
+    need_sum_g1 = any(
+        pack.channels[ci][1] in ("value", "square") for ci in sum_cols)
+    need_g1 = need_sum_g1 or (plan.p1_ell is None and minmax_cols)
+    g1 = None
+    if need_g1:
+        with jax.named_scope("pass1.sum" if need_sum_g1 else "pass1.minmax"):
+            g1 = jnp.take(values, plan.pass1.gather_padded)
     t_cols = {}
-    for ci in sum_cols:
-        src = pack.channels[ci][1]
-        if src == "ones":
-            # block cardinalities are host-exact plan metadata: the count
-            # channel skips pass 1 entirely (same as the per-agg path)
-            t_cols[ci] = plan.block_sizes
-        else:
-            t_cols[ci] = segment_sum_gathered(
-                plan.pass1, g1 if src == "value" else g1 * g1,
-                use_pallas=use_pallas, interpret=interpret)
-    for ci, mname, src in minmax_cols:
-        vsrc = values if src == "value" else values * values
-        gsrc = g1 if (g1 is None or src == "value") else g1 * g1
-        t_cols[ci] = _minmax_pass1(plan, vsrc, mname, gathered=gsrc)
+    with jax.named_scope("pass1.sum"):
+        for ci in sum_cols:
+            src = pack.channels[ci][1]
+            if src == "ones":
+                # block cardinalities are host-exact plan metadata: the
+                # count channel skips pass 1 entirely (same as the per-agg
+                # path)
+                t_cols[ci] = plan.block_sizes
+            else:
+                t_cols[ci] = segment_sum_gathered(
+                    plan.pass1, g1 if src == "value" else g1 * g1,
+                    use_pallas=use_pallas, interpret=interpret)
+    with jax.named_scope("pass1.minmax"):
+        for ci, mname, src in minmax_cols:
+            vsrc = values if src == "value" else values * values
+            gsrc = g1 if (g1 is None or src == "value") else g1 * g1
+            t_cols[ci] = _minmax_pass1(plan, vsrc, mname, gathered=gsrc)
 
     # ---- pass 2: one gather of the stacked sum-channel matrix; min/max
     # ride the dense ELL layout (idempotent monoids, order-insensitive) --- #
     outs = {}
     if sum_cols:
-        t_mat = jnp.stack([t_cols[ci] for ci in sum_cols], axis=1)
-        g2 = jnp.take(t_mat, plan.pass2.gather_padded, axis=0)  # [Lpad, C]
-        reduced = segment_sum_gathered(
-            plan.pass2, g2, use_pallas=use_pallas, interpret=interpret,
-        )
-        if reduced.ndim == 1:
-            reduced = reduced[:, None]
-        for j, ci in enumerate(sum_cols):
-            outs[ci] = reduced[:, j]
-    for ci, mname, _ in minmax_cols:
-        outs[ci] = _minmax_pass2(plan, t_cols[ci], mname)
+        with jax.named_scope("pass2.sum"):
+            t_mat = jnp.stack([t_cols[ci] for ci in sum_cols], axis=1)
+            g2 = jnp.take(t_mat, plan.pass2.gather_padded, axis=0)  # [Lpad, C]
+            reduced = segment_sum_gathered(
+                plan.pass2, g2, use_pallas=use_pallas, interpret=interpret,
+            )
+            if reduced.ndim == 1:
+                reduced = reduced[:, None]
+            for j, ci in enumerate(sum_cols):
+                outs[ci] = reduced[:, j]
+    with jax.named_scope("pass2.minmax"):
+        for ci, mname, _ in minmax_cols:
+            outs[ci] = _minmax_pass2(plan, t_cols[ci], mname)
     return tuple(outs[ci] for ci in range(len(pack.channels)))
 
 
@@ -696,26 +708,36 @@ def _query_iindex_multi_channels(plan: IIndexPlan, values, aggs: tuple,
     pack = pack_channels(aggs)
     values = jnp.asarray(values, jnp.float32)
     n = plan.n
-    ones = jnp.ones(n, jnp.float32)
-    srcs = {"value": values, "ones": ones, "square": values * values}
-    cols = jnp.stack([srcs[src] for _, src in pack.channels], axis=1)  # [n, C]
-    g = jnp.take(cols, plan.wd_plan.gather_padded, axis=0)  # one gather
-    chans = [None] * len(pack.channels)
     sum_cols = pack.channels_of("sum")
+    # named scopes as in _query_dbindex_multi_channels; the shared gather
+    # counts to the sums unless only min/max channels need it
+    with jax.named_scope("wd.sum" if sum_cols else "wd.minmax"):
+        ones = jnp.ones(n, jnp.float32)
+        srcs = {"value": values, "ones": ones, "square": values * values}
+        cols = jnp.stack([srcs[src] for _, src in pack.channels],
+                         axis=1)  # [n, C]
+        g = jnp.take(cols, plan.wd_plan.gather_padded, axis=0)  # one gather
+    chans = [None] * len(pack.channels)
     if sum_cols:
-        wdp = segment_sum_gathered(plan.wd_plan, g[:, list(sum_cols)],
-                                   use_pallas=use_pallas, interpret=interpret)
-        if wdp.ndim == 1:
-            wdp = wdp[:, None]
-        done = _inherit_scan(wdp, plan.pid, plan.level, plan.max_level, n,
-                             "sum", schedule)
-        for j, ci in enumerate(sum_cols):
-            chans[ci] = done[:, j]
+        with jax.named_scope("wd.sum"):
+            wdp = segment_sum_gathered(plan.wd_plan, g[:, list(sum_cols)],
+                                       use_pallas=use_pallas,
+                                       interpret=interpret)
+            if wdp.ndim == 1:
+                wdp = wdp[:, None]
+        with jax.named_scope("inherit.sum"):
+            done = _inherit_scan(wdp, plan.pid, plan.level, plan.max_level,
+                                 n, "sum", schedule)
+            for j, ci in enumerate(sum_cols):
+                chans[ci] = done[:, j]
     for mname in ("min", "max"):
         for ci in pack.channels_of(mname):
-            wdp = _segment_minmax_gathered(plan.wd_plan, g[:, ci], n, mname)
-            chans[ci] = _inherit_scan(wdp, plan.pid, plan.level,
-                                      plan.max_level, n, mname, schedule)
+            with jax.named_scope("wd.minmax"):
+                wdp = _segment_minmax_gathered(plan.wd_plan, g[:, ci], n,
+                                               mname)
+            with jax.named_scope("inherit.minmax"):
+                chans[ci] = _inherit_scan(wdp, plan.pid, plan.level,
+                                          plan.max_level, n, mname, schedule)
     return tuple(chans)
 
 

@@ -387,14 +387,19 @@ class StreamingEngine:
             idx2, self._base_links, self._base_blocks, self.batches_since_reorg
         ):
             with self.tracer.span("plan.patch", cat="update",
-                                  kind=self.index_kind, action="reorganize"):
+                                  kind=self.index_kind, action="reorganize",
+                                  rows=int(np.size(changed))):
                 self._build()
             reorganized = True
         elif self.device:
             from repro.core import engine_jax as ej
 
+            # the span times the host work and the enqueue of the device
+            # scatters, not the scatters themselves: those run under the
+            # ``plan.patch`` named scope (``ops.set_rows``)
             with self.tracer.span("plan.patch", cat="update",
-                                  kind=self.index_kind, action="patch"):
+                                  kind=self.index_kind, action="patch",
+                                  rows=int(np.size(changed))):
                 if self.index_kind == "dbindex":
                     self.plan = ej.patch_plan_dbindex(
                         self.plan, idx2, changed,

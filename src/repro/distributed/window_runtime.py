@@ -770,37 +770,29 @@ def _rebuild(index: DBIndex, splan: ShardedDBPlan,
                               headroom=splan.headroom, stats=stats, like=splan)
 
 
-def _scatter_rows(arr, ids, rows):
-    """``arr.at[ids].set(rows)`` on a mesh-sharded array.  The result keeps
-    ``arr``'s sharding: a scatter into an explicitly sharded array must
-    name its output sharding."""
-    import jax.numpy as jnp
-
-    return arr.at[jnp.asarray(ids)].set(jnp.asarray(rows),
-                                        out_sharding=arr.sharding)
-
-
 def _apply_patch(splan: ShardedDBPlan, msg: Dict,
                  stats: Dict) -> ShardedDBPlan:
     """Scatter one ``"patch"`` message into the device-resident shards —
     the leader's :func:`patch_sharded_plan` and a follower's
     :func:`apply_wire_message` run exactly these device updates."""
+    from repro.kernels.segment_reduce.ops import set_rows
+
     arrays = {"p1_seg": splan.p1_seg, "p1_gather": splan.p1_gather,
               "p2_seg": splan.p2_seg, "p2_gather": splan.p2_gather}
     for name, pos_np, seg_np, gather_np in msg["patches"]:
-        arrays[f"{name}_seg"] = _scatter_rows(arrays[f"{name}_seg"], pos_np,
-                                              seg_np)
-        arrays[f"{name}_gather"] = _scatter_rows(arrays[f"{name}_gather"],
-                                                 pos_np, gather_np)
+        arrays[f"{name}_seg"] = set_rows(arrays[f"{name}_seg"], pos_np,
+                                         seg_np)
+        arrays[f"{name}_gather"] = set_rows(arrays[f"{name}_gather"],
+                                            pos_np, gather_np)
     block_sizes = splan.block_sizes
     if msg["block_ids"].size:
-        block_sizes = _scatter_rows(block_sizes, msg["block_ids"],
-                                    msg["block_sizes"])
+        block_sizes = set_rows(block_sizes, msg["block_ids"],
+                               msg["block_sizes"])
     e1, e2 = splan.e1, splan.e2
     if msg["e1_rows"] is not None and msg["e1_ids"].size:
-        e1 = _scatter_rows(e1, msg["e1_ids"], msg["e1_rows"])
+        e1 = set_rows(e1, msg["e1_ids"], msg["e1_rows"])
     if msg["e2_rows"] is not None and msg["e2_ids"].size:
-        e2 = _scatter_rows(e2, msg["e2_ids"], msg["e2_rows"])
+        e2 = set_rows(e2, msg["e2_ids"], msg["e2_rows"])
     return dataclasses.replace(
         splan, num_blocks=int(msg["num_blocks"]), block_sizes=block_sizes,
         e1=e1, e2=e2, stats=stats, **arrays,
@@ -1130,12 +1122,14 @@ class ShardedStreamState:
             idx2, self._base_links, self._base_blocks, self.batches_since_reorg
         ):
             with self.tracer.span("plan.patch", cat="update",
-                                  kind=self.index_kind, action="reorganize"):
+                                  kind=self.index_kind, action="reorganize",
+                                  rows=int(np.size(changed))):
                 self._build()
             reorganized = True
         else:
             with self.tracer.span("plan.patch", cat="update",
-                                  kind=self.index_kind, action="patch"):
+                                  kind=self.index_kind, action="patch",
+                                  rows=int(np.size(changed))):
                 self.plan = patch_sharded_plan(
                     self.plan, idx2, changed,
                     compact_garbage=self.compact_garbage,

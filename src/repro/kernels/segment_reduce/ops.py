@@ -88,6 +88,17 @@ def keep_shape(prev: Optional[int], need: int, own: int) -> int:
     return int(own)
 
 
+@jax.jit
+def set_rows(arr, ids, rows):
+    """``arr.at[ids].set(rows)`` for an incremental plan patch, keeping
+    ``arr``'s sharding (a scatter into an explicitly sharded array must
+    name its output's).  Traced under the ``plan.patch`` named scope, so
+    the device trace files the patch's scatters under it: a scope reaches
+    only operations traced inside a jit, never eager ones."""
+    with jax.named_scope("plan.patch"):
+        return arr.at[ids].set(rows, out_sharding=jax.typeof(arr).sharding)
+
+
 def build_tile_plan(
     gather_idx: np.ndarray,
     segment_ids: np.ndarray,
@@ -255,10 +266,9 @@ def patch_tile_plan(
         gather_flat = plan.gather_padded
         if pos_chunks:
             pos = jnp.asarray(np.concatenate(pos_chunks))
-            seg_flat = seg_flat.at[pos].set(jnp.asarray(np.concatenate(seg_chunks)))
-            gather_flat = gather_flat.at[pos].set(
-                jnp.asarray(np.concatenate(gather_chunks))
-            )
+            seg_flat = set_rows(seg_flat, pos, np.concatenate(seg_chunks))
+            gather_flat = set_rows(gather_flat, pos,
+                                   np.concatenate(gather_chunks))
         return TilePlan(
             gather_padded=gather_flat,
             seg_tiles=seg_flat.reshape(nm, tm),

@@ -16,6 +16,15 @@ Completed spans land in a **ring buffer** (``collections.deque(maxlen)``,
 append is thread-safe under the GIL), so a long-running service keeps the
 most recent window of activity at O(1) cost and bounded memory.
 
+While ``jax`` is imported, each stack span of a live :class:`Tracer` also
+opens a ``jax.profiler.TraceAnnotation`` named ``repro.<name>`` on its
+thread: under a profiler session the program's spans then sit in the same
+trace as the device's operations, on the profiler's clock (no session: the
+annotation records nothing).  ``epoch_unix_ns`` maps the Tracer's own
+timestamps onto that clock.  A live Tracer also records each Python
+garbage collection as a root ``gc`` span (``generation``, ``collected``),
+mirrored the same way.
+
 Export is Chrome ``trace_event`` JSON (the ``chrome://tracing`` /
 Perfetto format): each span is one complete ``"ph": "X"`` event with
 ``ts``/``dur`` in microseconds, and ``args`` carrying ``span_id`` /
@@ -32,15 +41,31 @@ call per span site.
 
 from __future__ import annotations
 
+import gc
 import itertools
 import json
 import os
+import sys
 import threading
 import time
+import weakref
 from collections import deque
 from typing import Dict, List, Optional
 
 __all__ = ["Span", "Tracer", "NullTracer"]
+
+
+def _annotation(name: str):
+    """An entered ``jax.profiler.TraceAnnotation`` named ``repro.<name>``,
+    or None while jax is not imported (``import repro.obs`` never imports
+    it, as ``api.recompile_count`` never does)."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return None
+    ann = profiler.TraceAnnotation("repro." + name)
+    ann.__enter__()
+    return ann
 
 
 class Span:
@@ -49,11 +74,11 @@ class Span:
     the context-manager form — unless the span crosses threads."""
 
     __slots__ = ("tracer", "id", "parent_id", "name", "cat", "t0", "args",
-                 "tid", "_on_stack", "_done")
+                 "tid", "_on_stack", "_done", "_mirror")
 
     def __init__(self, tracer: "Tracer", span_id: int,
                  parent_id: Optional[int], name: str, cat: str,
-                 args: Dict, on_stack: bool):
+                 args: Dict, on_stack: bool, mirror: bool = False):
         self.tracer = tracer
         self.id = span_id
         self.parent_id = parent_id
@@ -64,15 +89,20 @@ class Span:
         self.tid = threading.get_ident()
         self._on_stack = on_stack
         self._done = False
+        self._mirror = _annotation(name) if mirror else None
 
     def set(self, **args) -> "Span":
         self.args.update(args)
         return self
 
-    def finish(self) -> None:
+    def finish(self, parent: Optional[int] = None) -> None:
+        """Record the span; ``parent`` re-links a detached span to the
+        span that completed it (a request to the flush that served it)."""
         if self._done:
             return
         self._done = True
+        if parent is not None:
+            self.parent_id = parent
         self.tracer._record(self)
 
     def __enter__(self) -> "Span":
@@ -83,6 +113,9 @@ class Span:
             self.args.setdefault("error", exc_type.__name__)
         if self._on_stack:
             self.tracer._pop(self)
+        if self._mirror is not None:
+            self._mirror.__exit__(None, None, None)
+            self._mirror = None
         self.finish()
 
 
@@ -95,11 +128,17 @@ class Tracer:
         self._ids = itertools.count(1)  # C-level next(): thread-safe
         self._tls = threading.local()
         self._epoch = time.perf_counter()
+        #: the epoch on the Unix clock, in ns, which the profiler stamps its
+        #: events with: a span's ``ts`` (us) maps to ``epoch_unix_ns +
+        #: 1e3 * ts`` there
+        self.epoch_unix_ns = time.time_ns()
         self.dropped_hint = 0  # events appended beyond capacity (approx.)
         # recorded thread names, by ident: threads register themselves via
         # name_thread() so the export stays legible even after they exit
         # (threading.enumerate() only sees live threads)
         self._thread_names: Dict[int, str] = {}
+        with _LIVE_LOCK:
+            _LIVE.add(self)
 
     # ------------------------------------------------------------------ #
     def _now(self) -> float:
@@ -118,7 +157,7 @@ class Tracer:
         stack = self._stack()
         parent = stack[-1].id if stack else None
         sp = Span(self, next(self._ids), parent, name, cat, args,
-                  on_stack=True)
+                  on_stack=True, mirror=True)
         stack.append(sp)
         return sp
 
@@ -237,7 +276,7 @@ class _NullSpan:
     def set(self, **args) -> "_NullSpan":
         return self
 
-    def finish(self) -> None:
+    def finish(self, parent=None) -> None:
         pass
 
     def __enter__(self) -> "_NullSpan":
@@ -292,3 +331,33 @@ class NullTracer:
 
 
 Tracer.enabled = True
+
+
+# ---------------------------------------------------------------------- #
+#  Garbage collections as spans
+# ---------------------------------------------------------------------- #
+# One gc callback for the process; it reaches the live tracers through a
+# WeakSet, so tracers that go out of use add no callbacks and no work.  A
+# collection's span is a root (no parent): it interrupts whatever its
+# thread was doing, and its ``tid`` says which thread that was.
+_LIVE: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_LIVE_LOCK = threading.RLock()
+_GC_OPEN = threading.local()  # the spans of the collection in progress
+
+
+def _on_gc(phase: str, info: Dict) -> None:
+    if phase == "start":
+        with _LIVE_LOCK:
+            tracers = list(_LIVE)
+        _GC_OPEN.spans = [
+            Span(t, next(t._ids), None, "gc", "gc",
+                 {"generation": info["generation"]}, on_stack=False,
+                 mirror=True)
+            for t in tracers]
+        return
+    for sp in getattr(_GC_OPEN, "spans", ()):
+        sp.set(collected=info["collected"]).__exit__(None, None, None)
+    _GC_OPEN.spans = ()
+
+
+gc.callbacks.append(_on_gc)

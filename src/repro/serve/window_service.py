@@ -122,6 +122,8 @@ class Ticket:
     error: Optional[BaseException] = None
     request_class: Optional[RequestClass] = None
     deadline_s: Optional[float] = None
+    #: when a flush took the ticket off the queue (``now_fn`` clock)
+    detached_s: Optional[float] = None
     _event: threading.Event = dataclasses.field(
         default_factory=threading.Event, repr=False, compare=False)
     _span: Optional[object] = dataclasses.field(
@@ -505,9 +507,14 @@ class WindowService:
         return (vec[vertex] if vertex is not None else vec.copy()), False
 
     def _take_pending(self) -> List[Ticket]:
-        """Atomically detach the queue (so a raise can never strand it)."""
+        """Atomically detach the queue (so a raise can never strand it),
+        stamping each ticket with the time it left the queue."""
         with self._lock:
             pending, self._pending = self._pending, []
+        if pending:
+            now = self.now()
+            for t in pending:
+                t.detached_s = now
         return pending
 
     def flush(self, reason: str = "manual") -> List[Ticket]:
@@ -531,11 +538,11 @@ class WindowService:
         if not pending:
             return pending
         with self.tracer.span("flush", cat="serve", reason=reason,
-                              pending=len(pending)):
-            return self._serve_inner(pending, reason)
+                              pending=len(pending)) as span:
+            return self._serve_inner(pending, reason, span.id)
 
-    def _serve_inner(self, pending: List[Ticket],
-                     reason: str) -> List[Ticket]:
+    def _serve_inner(self, pending: List[Ticket], reason: str,
+                     flush_id: Optional[int] = None) -> List[Ticket]:
         view = self._active
         groups = self.session.compiled.groups
         slots = self.session.compiled.spec_slots
@@ -605,7 +612,11 @@ class WindowService:
                 "ok" if t.error is None else "error")
             if t._span is not None:
                 t._span.set(version=t.version, cache_hit=t.cache_hit,
-                            ok=t.error is None).finish()
+                            ok=t.error is None, reason=reason)
+                if t.detached_s is not None:
+                    t._span.set(
+                        queued_ms=(t.detached_s - t.submitted_s) * 1e3)
+                t._span.finish(parent=flush_id)
             t._finish()
         self.flushes += 1
         self.served += ok
@@ -1033,18 +1044,10 @@ class AsyncWindowService(WindowService):
     def _flusher_loop(self) -> None:
         self.tracer.name_thread()
         while True:
-            reason = None
             with self._cv:
-                while reason is None:
-                    if self._stopping:
-                        return  # stop() drains (or fails) the leftovers
-                    reason, dl = self._due_reason()
-                    if reason is not None:
-                        break
-                    if dl is None:
-                        self._cv.wait(timeout=0.05)
-                        continue
-                    self._cv.wait(timeout=max(dl - self.now(), 1e-4))
+                reason = self._await_due()
+            if reason is None:
+                return  # stop() drains (or fails) the leftovers
             try:
                 self._flush_reason(reason)
             except Exception:
@@ -1052,6 +1055,32 @@ class AsyncWindowService(WindowService):
                 # is a bug in the scheduler itself — keep the loop alive,
                 # the queue was detached so no ticket is stranded
                 pass
+
+    def _await_due(self) -> Optional[str]:
+        """Wait (holding the condition) until the queue is due; its reason,
+        or None once the service stops.  The wait with tickets pending is
+        one ``flush.wait`` span, tagged with the reason it woke."""
+        wait = None
+        try:
+            while not self._stopping:
+                reason, dl = self._due_reason()
+                if reason is not None:
+                    if wait is not None:
+                        wait.set(reason=reason)
+                    return reason
+                if dl is None:
+                    if wait is not None:  # another flush took the queue
+                        wait.__exit__(None, None, None)
+                        wait = None
+                    self._cv.wait(timeout=0.05)
+                    continue
+                if wait is None:
+                    wait = self.tracer.span("flush.wait", cat="serve")
+                self._cv.wait(timeout=max(dl - self.now(), 1e-4))
+            return None
+        finally:
+            if wait is not None:
+                wait.__exit__(None, None, None)
 
     # --------------------------- durability --------------------------- #
     def update(self, batch) -> Dict:

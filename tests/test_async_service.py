@@ -443,3 +443,37 @@ def test_flusher_survives_flush_exception(monkeypatch):
         assert svc.running
         ok = svc.submit(0, vertex=0)
         assert ok.get(timeout=10.0) is not None
+
+
+def test_request_span_stamps_its_queue_wait_and_flush():
+    """A point read submitted at 0 and served by the deadline flush at
+    2 ms waited 2 ms in the queue, on the injected clock; its request span
+    says so, names the flush's reason, and hangs under the flush span."""
+    from repro.obs import Tracer
+
+    g, specs, sess = make_session(seed=47)
+    clk = FakeClock(0.0)
+    tr = Tracer()
+    svc = AsyncWindowService(sess, bucket=64, now_fn=clk, tracer=tr)
+    t = svc.submit(0, vertex=3)  # point class: 2 ms deadline
+    clk.advance(0.002)
+    assert [s.rid for s in svc.flush_if_due()] == [t.rid]
+    spans = {e["name"]: e for e in tr.events() if e["ph"] == "X"}
+    req, flush = spans["request"]["args"], spans["flush"]["args"]
+    assert req["queued_ms"] == 2.0 and req["reason"] == "deadline"
+    assert req["parent_id"] == flush["span_id"]
+    assert t.detached_s == 0.002
+
+
+def test_flusher_wait_span_carries_the_reason_it_woke():
+    """The background flusher's wait with a ticket pending is one
+    ``flush.wait`` span, tagged with the trigger that ended it."""
+    from repro.obs import Tracer
+
+    g, specs, sess = make_session(seed=49)
+    tr = Tracer()
+    with AsyncWindowService(sess, bucket=64, tracer=tr) as svc:
+        svc.submit(0, vertex=1).get(timeout=10.0)  # 2 ms deadline
+    waits = [e for e in tr.events() if e["name"] == "flush.wait"]
+    assert waits and waits[0]["args"]["reason"] == "deadline"
+    assert waits[0]["dur"] > 0
