@@ -60,6 +60,7 @@ from repro.core.aggregates import (
     AGGREGATES,
     ALL_REGISTERED,
     CHANNEL_AGG,
+    pack_channels,
     register_aggregate,  # noqa: F401  (re-export: the open-registry API)
 )
 from repro.core.graph import Graph
@@ -818,10 +819,12 @@ class Session:
         """One [B, n] batch through one materialized window.
 
         Device plans run the jitted batched fused executor (the Session's
-        ``use_pallas`` picks the Pallas or the XLA segment-sum, as for
+        ``use_pallas`` picks the Pallas or the XLA segment reduces, as for
         :meth:`run`); host engines loop the batch.  On the device path
         ``executor.device`` spans the launch until its channels are ready
-        and ``executor.finalize`` their fetch and the host finalizers.
+        (its ``minmax`` argument names the min/max route, see
+        :meth:`_minmax_args`) and ``executor.finalize`` their fetch and the
+        host finalizers.
         """
         if plan is not None and grp.engine in _VMANY_ENGINES:
             import jax
@@ -831,7 +834,8 @@ class Session:
 
             aggs = tuple(aggs)
             with self.tracer.span("executor.device", cat="query",
-                                  rows=len(vb)):
+                                  rows=len(vb),
+                                  **self._minmax_args(aggs, (plan,))):
                 chans = _get_vmany(grp.engine)(
                     plan, jnp.asarray(vb, jnp.float32), aggs,
                     self._opts["use_pallas"], self._opts["interpret"],
@@ -877,6 +881,20 @@ class Session:
                 term_outs.append(self._exec_term_many(
                     grp, term, index, plan, vb, g, prog.term_aggs))
         return _combine_program(prog, grp.aggs, term_outs)
+
+    def _minmax_args(self, aggs, plans) -> dict:
+        """The ``minmax`` span argument: how the min/max channels of
+        ``aggs`` reduce on ``plans`` (``"ell"``, ``"tiled"`` or ``"xla"``,
+        :func:`repro.core.engine_jax.minmax_route`), the terms' distinct
+        routes joined by ``+``; empty without a min/max aggregate or a
+        device plan."""
+        ej = sys.modules.get("repro.core.engine_jax")  # no plan without it
+        if ej is None or not any(
+                m in ("min", "max") for m, _ in pack_channels(aggs).channels):
+            return {}
+        routes = {ej.minmax_route(p, self._opts["use_pallas"])
+                  for p in plans} - {None}
+        return {"minmax": "+".join(sorted(routes))} if routes else {}
 
     def _term_span(self, grp: PlanGroup, term, **args):
         """``query.term``: one algebraic term of a composite program (a
@@ -1220,7 +1238,8 @@ class SessionView:
             if hit is not None:
                 return hit
         with self.session.tracer.span("query.group", cat="query", group=gi,
-                                      version=self.version):
+                                      version=self.version,
+                                      **self._minmax_args(gi)):
             out = self.session._exec_group(gi, self.artifacts[gi], values,
                                            graph=self.graph)
         if values is None and cache is not None:
@@ -1232,10 +1251,16 @@ class SessionView:
         materialized term on device engines (the scheduler's coalesced
         flush path)."""
         with self.session.tracer.span("query.group", cat="query", group=gi,
-                                      version=self.version, batched=True):
+                                      version=self.version, batched=True,
+                                      **self._minmax_args(gi)):
             return self.session._exec_group_many(gi, self.artifacts[gi],
                                                  values_batch,
                                                  graph=self.graph)
+
+    def _minmax_args(self, gi: int) -> dict:
+        return self.session._minmax_args(
+            self.session.compiled.groups[gi].aggs,
+            [plan for _, plan in self.artifacts[gi]])
 
     # ------------------------------------------------------------------ #
     def run(self, values=None) -> List[np.ndarray]:

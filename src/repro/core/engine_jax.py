@@ -3,7 +3,8 @@
 The host-built indices become static *plans* of device arrays:
 
 * DBIndex: two chained tile plans — members→blocks, then links→owners —
-  each one fused gather + Pallas segment-sum (DESIGN.md §2).
+  each one fused gather + Pallas segment-sum (DESIGN.md §2), and for
+  min/max the Pallas tiled segment min/max over the same tiles.
 * I-Index: one tile plan for the window-difference partials plus the PID
   forest; the inheritance scan is either level-scheduled (``depth`` gathers)
   or pointer-doubled (``log2(depth)`` gathers, the §Perf variant).
@@ -11,8 +12,10 @@ The host-built indices become static *plans* of device arrays:
 ``query_dbindex_multi`` / ``query_iindex_multi`` are the fused
 multi-aggregate executors behind :mod:`repro.core.api`: one gather per
 pass feeds every monoid channel (sum channels stack into a matrix reduce;
-min/max ride dense ELL layouts or per-monoid inheritance), so k aggregates
-over one window cost roughly one query instead of k.
+min/max reduce over dense ELL layouts where the plan has them, else over
+the tile layout, then per-monoid inheritance on the I-Index), so k
+aggregates over one window cost roughly one query instead of k.
+:func:`minmax_route` names which of those min/max reduces a plan takes.
 
 ``query_dbindex_sharded`` distributes the query under ``shard_map``:
 pass 1 is sharded over *blocks*, the (small) block-partial vector ``T`` is
@@ -38,6 +41,7 @@ from repro.kernels.segment_reduce.ops import (
     build_tile_plan,
     keep_shape,
     patch_tile_plan,
+    segment_minmax_gathered,
     segment_sum,
     segment_sum_gathered,
     set_rows,
@@ -64,10 +68,11 @@ class DBIndexPlan:
     ``p1_ell`` / ``p2_ell`` are padded per-segment row layouts (ELL style)
     for the idempotent monoids: blocks and owner link lists have tiny
     bounded fan-in, so min/max evaluate as one dense gather + axis reduce
-    instead of an XLA scatter.  min/max are order-insensitive, so the
-    formulation is bit-exact against any other evaluation order.  Pad slots
-    hold ``_ELL_SENTINEL``; ``jnp.take`` clips it to the last row of the
-    value vector, which the query extends with the monoid identity."""
+    instead of a segment reduce over the tile layout.  min/max are
+    order-insensitive, so the formulation is bit-exact against any other
+    evaluation order.  Pad slots hold ``_ELL_SENTINEL``; ``jnp.take`` clips
+    it to the last row of the value vector, which the query extends with
+    the monoid identity."""
 
     n: int
     num_blocks: int
@@ -147,8 +152,8 @@ def _ell_rows(offsets: np.ndarray, items: np.ndarray, num_rows: int,
 
 def _ell_from_index(index: DBIndex, cap: int, prev_widths=None):
     """(p1_ell, p2_ell) for the min/max fast path, or (None, None) when a
-    degenerate fan-in distribution would blow the padded layout up (the
-    scatter fallback stays available — min/max are exact either way).
+    degenerate fan-in distribution would blow the padded layout up (min/max
+    then reduce over the tile layout — exact either way).
     ``prev_widths``, the (R1, R2) of a plan being replaced, are kept where
     :func:`keep_shape` keeps them and the padding rule still holds."""
     max_block = int(np.diff(index.block_offsets).max()) if index.num_blocks else 1
@@ -156,11 +161,11 @@ def _ell_from_index(index: DBIndex, cap: int, prev_widths=None):
     own = _pow2(max_block), _pow2(max_links)
 
     def fits(r1, r2):
-        # the dense reduce beats the XLA scatter until padding inflates the
-        # row count by roughly an order of magnitude (scatter ~50-100ns/row
-        # vs ~1-2 ns/element dense); skewed fan-in distributions (one huge
-        # block, one hub owner linking thousands of blocks) fall back to
-        # the scatter path
+        # the dense reduce was sized against the XLA scatter (~9 ns per
+        # padded row on a v5e), which it beats until padding inflates the
+        # row count by roughly an order of magnitude; skewed fan-in
+        # distributions (one huge block, one hub owner linking thousands
+        # of blocks) reduce over the tile layout instead
         return (cap * r1 <= max(16 * index.block_members.size, 1 << 16)
                 and index.n * r2 <= max(16 * index.link_block.size, 1 << 16))
 
@@ -380,8 +385,9 @@ def query_dbindex(plan: DBIndexPlan, values, agg: str = "sum",
             return chans[0]
         return chans[0] / jnp.maximum(chans[1], 1e-30)
     if agg in ("min", "max"):
-        t = _minmax_pass1(plan, values, agg)
-        return _minmax_pass2(plan, t, agg)
+        t = _minmax_pass1(plan, values, agg, use_pallas=use_pallas,
+                          interpret=interpret)
+        return _minmax_pass2(plan, t, agg, use_pallas, interpret)
     raise ValueError(agg)
 
 
@@ -397,38 +403,38 @@ def _ell_reduce(ell, vec, op: str):
     return red(rows, axis=1)
 
 
-def _minmax_pass1(plan: DBIndexPlan, values, op: str, gathered=None):
+def minmax_route(plan, use_pallas: bool) -> Optional[str]:
+    """How ``plan``'s min/max channels reduce: ``"ell"`` (dense padded
+    layouts), ``"tiled"`` (the Pallas tiled segment min/max) or ``"xla"``
+    (masked XLA scatter-min/max), as the query functions below decide it;
+    None for a plan that is neither a DBIndex nor an I-Index plan."""
+    if isinstance(plan, DBIndexPlan) and plan.p1_ell is not None:
+        return "ell"
+    if isinstance(plan, (DBIndexPlan, IIndexPlan)):
+        return "tiled" if use_pallas else "xla"
+    return None
+
+
+def _minmax_pass1(plan: DBIndexPlan, values, op: str, gathered=None,
+                  use_pallas: bool = True, interpret: Optional[bool] = None):
     """Block partials for an idempotent monoid: ELL fast path when the plan
-    carries one, else the masked XLA segment lowering over the tile layout
+    carries one, else the tiled segment min/max over the pass-1 layout
     (sized by block_capacity — static under streamed updates)."""
     if plan.p1_ell is not None:
         return _ell_reduce(plan.p1_ell, values, op)
     if gathered is None:
         gathered = jnp.take(values, plan.pass1.gather_padded)
-    return _segment_minmax_gathered(plan.pass1, gathered,
-                                    plan.block_capacity, op)
+    return segment_minmax_gathered(plan.pass1, gathered, op,
+                                   interpret=interpret, use_pallas=use_pallas)
 
 
-def _minmax_pass2(plan: DBIndexPlan, t, op: str):
+def _minmax_pass2(plan: DBIndexPlan, t, op: str, use_pallas: bool = True,
+                  interpret: Optional[bool] = None):
     if plan.p2_ell is not None:
         return _ell_reduce(plan.p2_ell, t, op)
     gathered = jnp.take(t, plan.pass2.gather_padded)
-    return _segment_minmax_gathered(plan.pass2, gathered, plan.n, op)
-
-
-def _segment_minmax_gathered(plan, gathered, num_segments: int, op: str):
-    """Masked XLA segment min/max over pre-gathered rows in plan layout."""
-    sid = plan.seg_tiles.reshape(-1)
-    valid = sid >= 0
-    fill = jnp.inf if op == "min" else -jnp.inf
-    seg_op = jax.ops.segment_min if op == "min" else jax.ops.segment_max
-    if gathered.ndim == 1:
-        masked = jnp.where(valid, gathered, fill)
-    else:
-        masked = jnp.where(valid[:, None], gathered, fill)
-    out = seg_op(masked, jnp.where(valid, sid, num_segments),
-                 num_segments=num_segments + 1)
-    return out[:num_segments]
+    return segment_minmax_gathered(plan.pass2, gathered, op,
+                                   interpret=interpret, use_pallas=use_pallas)
 
 
 @functools.partial(jax.jit, static_argnames=("aggs", "use_pallas", "interpret"))
@@ -479,10 +485,12 @@ def _query_dbindex_multi_channels(plan: DBIndexPlan, values, aggs: tuple,
         for ci, mname, src in minmax_cols:
             vsrc = values if src == "value" else values * values
             gsrc = g1 if (g1 is None or src == "value") else g1 * g1
-            t_cols[ci] = _minmax_pass1(plan, vsrc, mname, gathered=gsrc)
+            t_cols[ci] = _minmax_pass1(plan, vsrc, mname, gathered=gsrc,
+                                       use_pallas=use_pallas,
+                                       interpret=interpret)
 
     # ---- pass 2: one gather of the stacked sum-channel matrix; min/max
-    # ride the dense ELL layout (idempotent monoids, order-insensitive) --- #
+    # reduce per channel, as in pass 1 (order-insensitive, so exact) ----- #
     outs = {}
     if sum_cols:
         with jax.named_scope("pass2.sum"):
@@ -497,7 +505,8 @@ def _query_dbindex_multi_channels(plan: DBIndexPlan, values, aggs: tuple,
                 outs[ci] = reduced[:, j]
     with jax.named_scope("pass2.minmax"):
         for ci, mname, _ in minmax_cols:
-            outs[ci] = _minmax_pass2(plan, t_cols[ci], mname)
+            outs[ci] = _minmax_pass2(plan, t_cols[ci], mname, use_pallas,
+                                     interpret)
     return tuple(outs[ci] for ci in range(len(pack.channels)))
 
 
@@ -733,8 +742,9 @@ def _query_iindex_multi_channels(plan: IIndexPlan, values, aggs: tuple,
     for mname in ("min", "max"):
         for ci in pack.channels_of(mname):
             with jax.named_scope("wd.minmax"):
-                wdp = _segment_minmax_gathered(plan.wd_plan, g[:, ci], n,
-                                               mname)
+                wdp = segment_minmax_gathered(plan.wd_plan, g[:, ci], mname,
+                                              interpret=interpret,
+                                              use_pallas=use_pallas)
             with jax.named_scope("inherit.minmax"):
                 chans[ci] = _inherit_scan(wdp, plan.pid, plan.level,
                                           plan.max_level, n, mname, schedule)
@@ -747,7 +757,8 @@ def query_iindex_multi(plan: IIndexPlan, values, aggs: tuple,
     """Fused multi-aggregate topological query via inheritance.
 
     One gather of the stacked channel matrix feeds every monoid's
-    window-difference reduce; the inheritance scan then runs once per
+    window-difference reduce (the Pallas segment sum, and the tiled segment
+    min/max per min/max channel); the inheritance scan then runs once per
     monoid (sum channels stacked into a single scan).  min/max ride the
     per-monoid level inheritance — containment (Theorem 5.1) makes the
     parent's finished aggregate a valid partial for *any* monoid, not just

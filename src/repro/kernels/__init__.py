@@ -1,7 +1,8 @@
 """Pallas TPU kernels for the perf-critical data planes.
 
 * ``segment_reduce`` — fused gather + tiled segment-sum (MXU one-hot
-  matmul).  The paper's entire query data plane (DBIndex pass 1/2, I-Index
+  matmul) and tiled segment min/max (masked VPU reduce) over one tile
+  plan.  The paper's entire query data plane (DBIndex pass 1/2, I-Index
   window differences) plus GNN message passing and recsys EmbeddingBag.
 * ``bitset_expand``  — packed-uint32 BFS hop (segmented OR scan + 16-bit
   split boundary extraction).  The paper's window computation.

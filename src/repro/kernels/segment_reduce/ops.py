@@ -5,11 +5,13 @@ renumbers nothing (ids are already dense) but groups rows by output tile and
 pads so the Pallas kernel sees a tile-aligned layout.  The returned plan is
 a pytree of device arrays with static shapes — exactly what pjit wants.
 
-``segment_sum(plan, values)`` = gather + Pallas tiled segment sum.
-``segment_reduce(...)`` adds the min/max fallbacks (XLA segment ops): the
-paper's experiments use SUM exclusively (§6 "the window query is conducted
-by using SUM()"), so the MXU path optimizes sum/count/avg and min/max ride
-the well-tuned XLA lowering.
+``segment_sum(plan, values)`` = gather + Pallas tiled segment sum (a
+one-hot matmul on the MXU).  ``segment_sum_gathered`` and
+``segment_minmax_gathered`` (Pallas tiled segment min/max, a masked VPU
+reduce over the same plan) take rows already gathered, so the fused
+queries share one gather between channels; ``use_pallas=False`` picks the
+XLA segment ops over the same tile-aligned inputs.  ``segment_reduce(...)``
+is the general entry point (min/max through the pure-jnp oracle).
 """
 
 from __future__ import annotations
@@ -26,6 +28,7 @@ from repro.kernels.compat import default_interpret as _default_interpret
 from repro.kernels.segment_reduce.segment_reduce import (
     DEFAULT_TM,
     DEFAULT_TS,
+    segment_minmax_tiled,
     segment_sum_tiled,
 )
 
@@ -359,6 +362,47 @@ def segment_sum(
     """Fused gather + tiled segment sum.  values: [N] or [N, D] -> [S(, D)]."""
     gathered = jnp.take(values, plan.gather_padded, axis=0)
     return segment_sum_gathered(plan, gathered, interpret, use_pallas)
+
+
+def segment_minmax_gathered(
+    plan: TilePlan,
+    gathered: jnp.ndarray,
+    op: str,
+    interpret: Optional[bool] = None,
+    use_pallas: bool = True,
+):
+    """Tiled segment min or max over pre-gathered rows ([Mpad] or
+    [Mpad, D]) -> [S(, D)] f32; a segment with no rows holds the identity
+    (+inf for min, -inf for max), as ``jax.ops.segment_min``/``max`` give.
+
+    Traceable, like :func:`segment_sum_gathered`.  Both paths are exact:
+    min and max do not depend on the order of the rows."""
+    interpret = _default_interpret() if interpret is None else interpret
+    squeeze = gathered.ndim == 1
+    v = (gathered[:, None] if squeeze else gathered).astype(jnp.float32)
+    if use_pallas:
+        out = segment_minmax_tiled(
+            v.T,
+            plan.seg_tiles,
+            plan.m2out,
+            plan.first_visit,
+            op=op,
+            num_out_tiles=plan.num_out_tiles,
+            tm=plan.tm,
+            ts=plan.ts,
+            interpret=interpret,
+        ).T[: plan.num_segments]
+    else:  # XLA fallback: masked scatter-min/max over the same layout
+        sid = plan.seg_tiles.reshape(-1)
+        ok = sid >= 0
+        fill = jnp.inf if op == "min" else -jnp.inf
+        seg_op = jax.ops.segment_min if op == "min" else jax.ops.segment_max
+        out = seg_op(
+            jnp.where(ok[:, None], v, fill),
+            jnp.where(ok, sid, plan.num_segments),
+            num_segments=plan.num_segments + 1,
+        )[:-1]
+    return out[:, 0] if squeeze else out
 
 
 def segment_reduce(

@@ -14,7 +14,8 @@ answering the *why* questions the metric counters cannot:
   combine, or pairwise inclusion–exclusion (with the rejected alternative
   named);
 * **plan anatomy** — per materialized term: blocks, tile groups, ELL
-  layouts, headroom utilization (real vs padded rows), garbage fraction,
+  layouts, the min/max route (``ell``, ``tiled`` or ``xla``), headroom
+  utilization (real vs padded rows), garbage fraction,
   and shard layout balance for :class:`ShardedDBPlan`;
 * **memory footprint** — exact per-array device bytes via the plan
   classes' ``array_nbytes()`` / ``plan_nbytes()`` (the accounting ROADMAP
@@ -313,12 +314,17 @@ def _index_anatomy(index) -> Tuple[Optional[str], Dict]:
     return cls.lower(), {"type": cls}
 
 
-def _plan_anatomy(plan, index) -> Tuple[Optional[str], Dict, Dict]:
+def _plan_anatomy(plan, index, use_pallas: bool
+                  ) -> Tuple[Optional[str], Dict, Dict]:
     """(plan_kind, anatomy, array_nbytes) for any of the three plan classes
-    (or a host-only/stateless term with no device plan)."""
+    (or a host-only/stateless term with no device plan).  ``use_pallas``
+    is the session's, which with the plan decides the min/max route."""
     if plan is None:
         return None, {}, {}
+    from repro.core.engine_jax import minmax_route  # loaded with any plan
+
     cls = type(plan).__name__
+    route = minmax_route(plan, use_pallas)
     if cls == "DBIndexPlan":
         real1 = int(index.block_members.size) if index is not None else None
         real2 = int(index.link_block.size) if index is not None else None
@@ -339,6 +345,7 @@ def _plan_anatomy(plan, index) -> Tuple[Optional[str], Dict, Dict]:
                 "p2_width": (int(plan.p2_ell.shape[1])
                              if plan.p2_ell is not None else None),
             },
+            "minmax_route": route,
         }
         if real1 is not None:
             anat["pass1_rows_real"] = real1
@@ -355,6 +362,7 @@ def _plan_anatomy(plan, index) -> Tuple[Optional[str], Dict, Dict]:
             "wd_rows_padded": pad,
             "wd_tile_groups": int(plan.wd_plan.num_out_tiles),
             "tile": {"tm": int(plan.wd_plan.tm), "ts": int(plan.wd_plan.ts)},
+            "minmax_route": route,
         }
         if real is not None:
             anat["wd_rows_real"] = real
@@ -451,7 +459,8 @@ def explain_session(session, spec=None) -> PlanReport:
         arts = session._group_artifacts(gi)
         for term, (index, plan) in zip(session._group_terms(gi), arts):
             ikind, ianat = _index_anatomy(index)
-            pkind, panat, nb = _plan_anatomy(plan, index)
+            pkind, panat, nb = _plan_anatomy(plan, index,
+                                             session._opts["use_pallas"])
             pbytes = sum(nb.values())
             gbytes += pbytes
             terms.append(TermReport(

@@ -142,7 +142,9 @@ def _analyze_dbindex_term(clock: _PhaseClock, gi: int, tname: str, plan,
         for ci, mname, src in minmax_cols:
             vsrc = values if src == "value" else values * values
             gsrc = g1 if (g1 is None or src == "value") else g1 * g1
-            t_cols[ci] = _minmax_pass1(plan, vsrc, mname, gathered=gsrc)
+            t_cols[ci] = _minmax_pass1(plan, vsrc, mname, gathered=gsrc,
+                                       use_pallas=use_pallas,
+                                       interpret=interpret)
         return t_cols
 
     t_cols = clock.timed(gi, tname, "pass1_reduce", _pass1)
@@ -165,7 +167,8 @@ def _analyze_dbindex_term(clock: _PhaseClock, gi: int, tname: str, plan,
         outs.update(clock.timed(gi, tname, "pass2_reduce", _pass2))
     if minmax_cols:
         def _pass2_minmax():
-            return {ci: _minmax_pass2(plan, t_cols[ci], mname)
+            return {ci: _minmax_pass2(plan, t_cols[ci], mname, use_pallas,
+                                      interpret)
                     for ci, mname, _ in minmax_cols}
 
         outs.update(clock.timed(gi, tname, "pass2_reduce", _pass2_minmax))
@@ -181,11 +184,11 @@ def _analyze_iindex_term(clock: _PhaseClock, gi: int, tname: str, plan,
     import jax.numpy as jnp
 
     from repro.core.aggregates import pack_channels
-    from repro.core.engine_jax import (
-        _inherit_scan,
-        _segment_minmax_gathered,
+    from repro.core.engine_jax import _inherit_scan
+    from repro.kernels.segment_reduce.ops import (
+        segment_minmax_gathered,
+        segment_sum_gathered,
     )
-    from repro.kernels.segment_reduce.ops import segment_sum_gathered
 
     use_pallas = opts.get("use_pallas", True)
     interpret = opts.get("interpret")
@@ -216,8 +219,9 @@ def _analyze_iindex_term(clock: _PhaseClock, gi: int, tname: str, plan,
             for ci in pack.channels_of(mname):
                 # string key: pytree dict flatten sorts keys, so mixing
                 # str and tuple keys would break block_until_ready
-                parts[f"{mname}:{ci}"] = _segment_minmax_gathered(
-                    plan.wd_plan, g[:, ci], n, mname)
+                parts[f"{mname}:{ci}"] = segment_minmax_gathered(
+                    plan.wd_plan, g[:, ci], mname, interpret=interpret,
+                    use_pallas=use_pallas)
         return parts
 
     parts = clock.timed(gi, tname, "wd_reduce", _wd_reduce)

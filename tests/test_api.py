@@ -31,7 +31,12 @@ from repro.core.iindex import build_iindex  # noqa: E402
 from repro.core.query import brute_force  # noqa: E402
 from repro.core.streaming import StreamingEngine  # noqa: E402
 from repro.core.windows import KHopWindow, TopologicalWindow  # noqa: E402
-from repro.graphs.generators import erdos_renyi, random_dag, with_random_attrs  # noqa: E402
+from repro.graphs.generators import (  # noqa: E402
+    barabasi_albert,
+    erdos_renyi,
+    random_dag,
+    with_random_attrs,
+)
 
 from test_updates import mixed  # noqa: E402  (stream helpers)
 
@@ -145,6 +150,68 @@ def test_fused_dbindex_multi_pallas_interpret(khop_case):
                                    use_pallas=True, interpret=True)
     for a, got in zip(("sum", "avg"), fused):
         assert np.allclose(np.asarray(got), refs[a], rtol=1e-5, atol=1e-3), a
+
+
+def _hub_tree_plan():
+    """A Barabási–Albert tree: its hubs make blocks too wide for the ELL
+    layout, so min/max reduce over the tile layout."""
+    g = with_random_attrs(barabasi_albert(500, 1, seed=0), seed=14)
+    plan = ej.plan_from_dbindex(build_dbindex(g, KHopWindow(2)),
+                                tm=128, ts=128)
+    return g, plan
+
+
+def test_fused_dbindex_multi_minmax_tiled_matches_xla():
+    g, plan = _hub_tree_plan()
+    assert plan.p1_ell is None
+    assert ej.minmax_route(plan, True) == "tiled"
+    assert ej.minmax_route(plan, False) == "xla"
+    v = g.attrs["val"]
+    tiled = ej.query_dbindex_multi(plan, v, ALL_AGGS, use_pallas=True,
+                                   interpret=True)
+    xla = ej.query_dbindex_multi(plan, v, ALL_AGGS, use_pallas=False)
+    for a, got, want in zip(ALL_AGGS, tiled, xla):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want), a)
+    for a in ("min", "max"):
+        single = ej.query_dbindex(plan, v, a, use_pallas=True, interpret=True)
+        np.testing.assert_array_equal(np.asarray(single),
+                                      brute_force(g, KHopWindow(2), v, a))
+
+
+def test_fused_iindex_multi_minmax_tiled_matches_xla():
+    g = with_random_attrs(random_dag(400, 2.0, seed=15), seed=16)
+    plan = ej.plan_from_iindex(build_iindex(g), tm=128, ts=128)
+    assert ej.minmax_route(plan, True) == "tiled"
+    v = g.attrs["val"]
+    tiled = ej.query_iindex_multi(plan, v, ALL_AGGS, use_pallas=True,
+                                  interpret=True)
+    xla = ej.query_iindex_multi(plan, v, ALL_AGGS, use_pallas=False)
+    for a, got, want in zip(ALL_AGGS, tiled, xla):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want), a)
+    for a in ("min", "max"):
+        np.testing.assert_array_equal(
+            np.asarray(tiled[ALL_AGGS.index(a)]),
+            brute_force(g, TopologicalWindow(), v, a))
+
+
+def test_spans_name_the_minmax_route():
+    """``query.group`` and ``executor.device`` carry ``minmax``, the route
+    the group's min/max channels take; a group without one carries none."""
+    from repro.obs.tracing import Tracer
+
+    g, _ = _hub_tree_plan()
+    specs = [QuerySpec(("khop", 2), "min"), QuerySpec(("khop", 1), "max"),
+             QuerySpec(("khop", 2), "sum", engine="dbindex")]
+    tr = Tracer()
+    sess = Session(g, specs, device=True, use_pallas=False, tracer=tr)
+    sess.run()
+    sess.snapshot().run_many(np.stack([g.attrs["val"]] * 2))
+    routes = {}
+    for e in tr.events():
+        if e["name"] in ("query.group", "executor.device"):
+            routes.setdefault(e["name"], []).append(e["args"].get("minmax"))
+    assert routes["query.group"] == ["xla", "ell", None] * 2
+    assert routes["executor.device"] == ["xla", "ell"]
 
 
 @pytest.mark.parametrize("schedule", ["level", "doubling"])

@@ -9,6 +9,8 @@ The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this file.
 """
 
+import re
+
 import numpy as np
 import pytest
 
@@ -61,6 +63,25 @@ def test_segment_sum_tiled_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in _compile_text(f, *_seg_sum_args(one_chip, 128))
 
 
+def _has_minmax_kernel(text: str) -> bool:
+    """The tiled segment min/max's custom call is in compiled HLO text."""
+    return re.search(r"%segment_minmax_tiled\S* = .*tpu_custom_call",
+                     text) is not None
+
+
+@pytest.mark.parametrize("op,d", [("min", 1), ("max", 3)])
+def test_segment_minmax_tiled_compiles_for_v5e(op, d, one_chip):
+    from repro.kernels.segment_reduce.segment_reduce import (
+        segment_minmax_tiled,
+    )
+
+    def f(v, s, m, fv):
+        return segment_minmax_tiled(v, s, m, fv, op=op, num_out_tiles=4,
+                                    tm=512, ts=512)
+
+    assert _has_minmax_kernel(_compile_text(f, *_seg_sum_args(one_chip, d)))
+
+
 def test_vmapped_segment_sum_compiles_for_v5e(one_chip):
     from repro.kernels.segment_reduce.segment_reduce import segment_sum_tiled
 
@@ -95,30 +116,47 @@ def _small_plan(engine):
     from repro.core.dbindex import build_dbindex
     from repro.core.iindex import build_iindex
     from repro.core.windows import KHopWindow
-    from repro.graphs.generators import erdos_renyi, random_dag
+    from repro.graphs.generators import barabasi_albert, erdos_renyi, random_dag
 
     if engine == "jax":
         g = erdos_renyi(600, 6.0, directed=False, seed=0)
         return g.n, ej.plan_from_dbindex(build_dbindex(g, KHopWindow(2)),
                                          headroom=0.5)
+    if engine == "jax-no-ell":  # a hub tree: blocks too wide for ELL
+        g = barabasi_albert(500, 1, seed=0)
+        return g.n, ej.plan_from_dbindex(build_dbindex(g, KHopWindow(2)))
     g = random_dag(600, 3.0, seed=0)
     return g.n, ej.plan_from_iindex(build_iindex(g))
+
+
+def _batched_executor_text(engine, plan, n, one_chip) -> str:
+    from repro.core.api import _get_vmany
+
+    shapes = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype,
+                                       sharding=one_chip), plan)
+    vb = jax.ShapeDtypeStruct((8, n), jnp.float32, sharding=one_chip)
+    return _get_vmany(engine).lower(shapes, vb, AGGS, True, False) \
+        .compile().as_text()
 
 
 @pytest.mark.parametrize("engine", ["jax", "jax-iindex"])
 def test_batched_serving_executor_runs_pallas_on_v5e(engine, one_chip):
     """The WindowService's [bucket, n] launch, with the Session's default
-    ``use_pallas=True``, compiles with the segment-sum kernel in it."""
-    from repro.core.api import _get_vmany
-
+    ``use_pallas=True``, compiles with the segment-sum kernel in it (and
+    on the I-Index, the tiled segment min/max)."""
     n, plan = _small_plan(engine)
-    shapes = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(np.shape(a), jnp.asarray(a).dtype,
-                                       sharding=one_chip), plan)
-    vb = jax.ShapeDtypeStruct((8, n), jnp.float32, sharding=one_chip)
-    text = _get_vmany(engine).lower(shapes, vb, AGGS, True, False) \
-        .compile().as_text()
+    text = _batched_executor_text(engine, plan, n, one_chip)
     assert "tpu_custom_call" in text
+    assert _has_minmax_kernel(text) == (engine == "jax-iindex")
+
+
+def test_batched_executor_without_ell_runs_tiled_minmax_on_v5e(one_chip):
+    """On a DBIndex plan without ELL layouts, the batched launch's min/max
+    compile to the tiled segment min/max kernel."""
+    n, plan = _small_plan("jax-no-ell")
+    assert plan.p1_ell is None
+    assert _has_minmax_kernel(_batched_executor_text("jax", plan, n, one_chip))
 
 
 def test_sharded_query_compiles_on_a_4_chip_mesh(topo):

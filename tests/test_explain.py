@@ -191,6 +191,29 @@ def test_composite_lowering_choices():
                for r in lo_sum["rejected"])
 
 
+@pytest.mark.parametrize("use_pallas", [True, False])
+def test_explain_names_the_minmax_route(use_pallas):
+    """EXPLAIN names how each term's min/max reduce: ``ell`` on a DBIndex
+    plan with ELL layouts, else ``tiled`` (Pallas) or ``xla``; the I-Index
+    never has ELL layouts.  The text view shows it too."""
+    from repro.graphs.generators import barabasi_albert
+
+    tiled = "tiled" if use_pallas else "xla"
+    cases = [
+        (erdos_renyi(200, 4.0, directed=False, seed=1), ("khop", 1), "ell"),
+        # a hub tree: its blocks are too wide for the ELL layout
+        (barabasi_albert(500, 1, seed=0), ("khop", 2), tiled),
+        (random_dag(150, 2.0, seed=2), "topological", tiled),
+    ]
+    for g, window, route in cases:
+        sess = Session(with_random_attrs(g, seed=5),
+                       [QuerySpec(window, "max")], device=True,
+                       use_pallas=use_pallas)
+        rep = sess.explain()
+        assert rep.groups[0].terms[0].plan["minmax_route"] == route, window
+        assert f"plan.minmax_route: {route}" in rep.text()
+
+
 def test_explain_spec_filter_selects_one_group():
     g = with_random_attrs(erdos_renyi(200, 4.0, directed=False, seed=1),
                           seed=2)

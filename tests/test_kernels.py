@@ -79,6 +79,81 @@ def test_segment_min_max_fallback():
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
 
 
+def _minmax_case(case):
+    """(plan, vals, gather_idx, segment_ids, num_segments, d) for one
+    tiled segment min/max case: rows sorted by segment, as the index
+    builders give them."""
+    from repro.kernels.segment_reduce.ops import patch_tile_plan
+
+    rng = np.random.default_rng(len(case))
+    d = 3 if case in ("empty", "headroom", "spanning_d3") else 1
+    s = 1100  # three output tiles of 512 segment ids
+    if case.startswith("spanning"):
+        # segment 7 alone fills three 512-row input tiles of output tile 0
+        seg = np.sort(np.concatenate([np.full(1400, 7),
+                                      rng.integers(0, s, 900)]))
+    elif case == "empty":
+        # output tile 1 (ids 512-1023) and most single ids get no row
+        seg = np.sort(np.concatenate([rng.integers(0, 40, 300),
+                                      rng.integers(1024, s, 200)]))
+    else:
+        seg = np.sort(rng.integers(0, s, 2500))
+    n = 400
+    vals = rng.integers(-50, 50, (n, d)).astype(np.float32)
+    if case == "inf":
+        vals[rng.integers(0, n, 40), 0] = np.inf
+        vals[rng.integers(0, n, 40), 0] = -np.inf
+    gidx = rng.integers(0, n, seg.size).astype(np.int32)
+    plan = build_tile_plan(gidx, seg, s,
+                           headroom=0.5 if case == "headroom" else 0.0)
+    if case.startswith("patched"):
+        # rows of segments 3 and 900 change (and, when grown, ids past the
+        # old space appear): the patcher re-lays-out only their groups
+        keep = (seg != 3) & (seg != 900)
+        grow = 1700 if case == "patched_grown" else s
+        extra = np.concatenate([np.full(5, 3), np.full(9, 900),
+                                np.arange(s, grow)])
+        seg = np.concatenate([seg[keep], extra])
+        order = np.argsort(seg, kind="stable")
+        seg = seg[order]
+        gidx = np.concatenate([gidx[keep],
+                               rng.integers(0, n, extra.size)])[order]
+        gidx = gidx.astype(np.int32)
+        changed = np.concatenate([[3, 900], np.arange(s, grow)])
+        plan = patch_tile_plan(plan, gidx, seg, grow, changed)
+        s = grow
+    return plan, vals, gidx, seg, s, d
+
+
+@pytest.mark.parametrize("case", [
+    "spanning", "spanning_d3", "empty", "inf", "headroom", "patched",
+    "patched_grown",
+])
+@pytest.mark.parametrize("op", ["min", "max"])
+def test_segment_minmax_tiled_matches_xla(op, case):
+    """The Pallas tiled segment min/max equals the masked XLA
+    scatter-min/max on the same plan, element for element, and both equal
+    the oracle: empty segments hold the identity (+inf / -inf)."""
+    from repro.kernels.segment_reduce.ops import segment_minmax_gathered
+
+    plan, vals, gidx, seg, s, d = _minmax_case(case)
+    g = jnp.take(jnp.asarray(vals), plan.gather_padded, axis=0)
+    if d == 1:
+        g = g[:, 0]
+    tiled = segment_minmax_gathered(plan, g, op, interpret=True)
+    xla = segment_minmax_gathered(plan, g, op, use_pallas=False)
+    assert tiled.shape == xla.shape == ((s, d) if d > 1 else (s,))
+    np.testing.assert_array_equal(np.asarray(tiled), np.asarray(xla))
+    ref = segment_reduce_ref(jnp.asarray(vals), jnp.asarray(gidx),
+                             jnp.asarray(seg, jnp.int32), s, op)
+    np.testing.assert_array_equal(np.asarray(tiled).reshape(s, d),
+                                  np.asarray(ref))
+    empty = np.bincount(seg, minlength=s) == 0
+    assert empty.any() or case.startswith("spanning")
+    ident = np.inf if op == "min" else -np.inf
+    assert (np.asarray(tiled).reshape(s, d)[empty] == ident).all()
+
+
 # ------------------------------ bitset ------------------------------- #
 @pytest.mark.parametrize("n,deg,k", [(200, 4.0, 1), (300, 6.0, 2), (150, 3.0, 3)])
 def test_bitset_expand_sweep(n, deg, k):
