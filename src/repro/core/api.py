@@ -822,9 +822,9 @@ class Session:
         ``use_pallas`` picks the Pallas or the XLA segment reduces, as for
         :meth:`run`); host engines loop the batch.  On the device path
         ``executor.device`` spans the launch until its channels are ready
-        (its ``minmax`` argument names the min/max route, see
-        :meth:`_minmax_args`) and ``executor.finalize`` their fetch and the
-        host finalizers.
+        (its ``minmax`` and ``pages`` arguments name the min/max route and
+        the kernel pages, see :meth:`_plan_args`) and ``executor.finalize``
+        their fetch and the host finalizers.
         """
         if plan is not None and grp.engine in _VMANY_ENGINES:
             import jax
@@ -835,7 +835,7 @@ class Session:
             aggs = tuple(aggs)
             with self.tracer.span("executor.device", cat="query",
                                   rows=len(vb),
-                                  **self._minmax_args(aggs, (plan,))):
+                                  **self._plan_args(aggs, (plan,))):
                 chans = _get_vmany(grp.engine)(
                     plan, jnp.asarray(vb, jnp.float32), aggs,
                     self._opts["use_pallas"], self._opts["interpret"],
@@ -882,19 +882,32 @@ class Session:
                     grp, term, index, plan, vb, g, prog.term_aggs))
         return _combine_program(prog, grp.aggs, term_outs)
 
-    def _minmax_args(self, aggs, plans) -> dict:
-        """The ``minmax`` span argument: how the min/max channels of
-        ``aggs`` reduce on ``plans`` (``"ell"``, ``"tiled"`` or ``"xla"``,
-        :func:`repro.core.engine_jax.minmax_route`), the terms' distinct
-        routes joined by ``+``; empty without a min/max aggregate or a
-        device plan."""
+    def _plan_args(self, aggs, plans) -> dict:
+        """The ``minmax`` and ``pages`` span arguments of ``plans``.
+
+        ``minmax``: how the min/max channels of ``aggs`` reduce (``"ell"``,
+        ``"tiled"`` or ``"xla"``, :func:`repro.core.engine_jax.minmax_route`),
+        the terms' distinct routes joined by ``+``; left out without a
+        min/max aggregate.  ``pages``: the Pallas calls each segment kernel
+        takes over the largest pass of any term
+        (:func:`repro.core.engine_jax.plan_pages`, 1 up to
+        ``segment_reduce.PAGE_TILES`` tiles); left out where the XLA
+        segment ops run (``use_pallas=False``).  Both are left out without
+        a device plan."""
         ej = sys.modules.get("repro.core.engine_jax")  # no plan without it
-        if ej is None or not any(
-                m in ("min", "max") for m, _ in pack_channels(aggs).channels):
+        if ej is None:
             return {}
-        routes = {ej.minmax_route(p, self._opts["use_pallas"])
-                  for p in plans} - {None}
-        return {"minmax": "+".join(sorted(routes))} if routes else {}
+        args = {}
+        if any(m in ("min", "max") for m, _ in pack_channels(aggs).channels):
+            routes = {ej.minmax_route(p, self._opts["use_pallas"])
+                      for p in plans} - {None}
+            if routes:
+                args["minmax"] = "+".join(sorted(routes))
+        if self._opts["use_pallas"]:
+            pages = [p for p in map(ej.plan_pages, plans) if p is not None]
+            if pages:
+                args["pages"] = max(pages)
+        return args
 
     def _term_span(self, grp: PlanGroup, term, **args):
         """``query.term``: one algebraic term of a composite program (a
@@ -1239,7 +1252,7 @@ class SessionView:
                 return hit
         with self.session.tracer.span("query.group", cat="query", group=gi,
                                       version=self.version,
-                                      **self._minmax_args(gi)):
+                                      **self._plan_args(gi)):
             out = self.session._exec_group(gi, self.artifacts[gi], values,
                                            graph=self.graph)
         if values is None and cache is not None:
@@ -1252,13 +1265,13 @@ class SessionView:
         flush path)."""
         with self.session.tracer.span("query.group", cat="query", group=gi,
                                       version=self.version, batched=True,
-                                      **self._minmax_args(gi)):
+                                      **self._plan_args(gi)):
             return self.session._exec_group_many(gi, self.artifacts[gi],
                                                  values_batch,
                                                  graph=self.graph)
 
-    def _minmax_args(self, gi: int) -> dict:
-        return self.session._minmax_args(
+    def _plan_args(self, gi: int) -> dict:
+        return self.session._plan_args(
             self.session.compiled.groups[gi].aggs,
             [plan for _, plan in self.artifacts[gi]])
 

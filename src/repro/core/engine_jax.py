@@ -15,7 +15,8 @@ pass feeds every monoid channel (sum channels stack into a matrix reduce;
 min/max reduce over dense ELL layouts where the plan has them, else over
 the tile layout, then per-monoid inheritance on the I-Index), so k
 aggregates over one window cost roughly one query instead of k.
-:func:`minmax_route` names which of those min/max reduces a plan takes.
+:func:`minmax_route` names which of those min/max reduces a plan takes,
+and :func:`plan_pages` how many kernel calls its largest pass takes.
 
 ``query_dbindex_sharded`` distributes the query under ``shard_map``:
 pass 1 is sharded over *blocks*, the (small) block-partial vector ``T`` is
@@ -412,6 +413,17 @@ def minmax_route(plan, use_pallas: bool) -> Optional[str]:
         return "ell"
     if isinstance(plan, (DBIndexPlan, IIndexPlan)):
         return "tiled" if use_pallas else "xla"
+    return None
+
+
+def plan_pages(plan) -> Optional[int]:
+    """Pallas calls each segment kernel takes over ``plan``'s largest pass
+    (:attr:`TilePlan.pages`: 1 up to ``segment_reduce.PAGE_TILES`` tiles);
+    None for a plan that is neither a DBIndex nor an I-Index plan."""
+    if isinstance(plan, DBIndexPlan):
+        return max(plan.pass1.pages, plan.pass2.pages)
+    if isinstance(plan, IIndexPlan):
+        return plan.wd_plan.pages
     return None
 
 

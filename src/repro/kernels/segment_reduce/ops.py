@@ -10,8 +10,13 @@ one-hot matmul on the MXU).  ``segment_sum_gathered`` and
 ``segment_minmax_gathered`` (Pallas tiled segment min/max, a masked VPU
 reduce over the same plan) take rows already gathered, so the fused
 queries share one gather between channels; ``use_pallas=False`` picks the
-XLA segment ops over the same tile-aligned inputs.  ``segment_reduce(...)``
-is the general entry point (min/max through the pure-jnp oracle).
+XLA segment ops over the same tile-aligned inputs.  Both kernels reduce a
+plan of more than ``segment_reduce.PAGE_TILES`` tiles in pages of that
+many tiles (:attr:`TilePlan.pages` calls, bit-identical to one call; the
+``segment_reduce`` module docstring gives the rule and the derivation of
+the constant), so the tables a call keeps in scalar memory never grow
+with the plan.  ``segment_reduce(...)`` is the general entry point
+(min/max through the pure-jnp oracle).
 """
 
 from __future__ import annotations
@@ -28,6 +33,7 @@ from repro.kernels.compat import default_interpret as _default_interpret
 from repro.kernels.segment_reduce.segment_reduce import (
     DEFAULT_TM,
     DEFAULT_TS,
+    num_pages,
     segment_minmax_tiled,
     segment_sum_tiled,
 )
@@ -67,6 +73,13 @@ class TilePlan:
 
     def plan_nbytes(self) -> int:
         return sum(self.array_nbytes().values())
+
+    @property
+    def pages(self) -> int:
+        """Pallas calls each segment kernel reduces this plan in (1 up to
+        ``segment_reduce.PAGE_TILES`` tiles; set by the static tile count,
+        so a patched plan keeps it)."""
+        return num_pages(self.seg_tiles.shape[0])
 
 
 jax.tree_util.register_pytree_node(
@@ -324,6 +337,8 @@ def segment_sum_gathered(
 
     Traceable (no jit of its own): fused multi-channel queries call this
     after a single shared ``jnp.take`` so k aggregates pay for one gather.
+    The Pallas path takes ``plan.pages`` kernel calls (one up to
+    ``PAGE_TILES`` tiles).
     """
     interpret = _default_interpret() if interpret is None else interpret
     squeeze = gathered.ndim == 1
@@ -375,8 +390,8 @@ def segment_minmax_gathered(
     [Mpad, D]) -> [S(, D)] f32; a segment with no rows holds the identity
     (+inf for min, -inf for max), as ``jax.ops.segment_min``/``max`` give.
 
-    Traceable, like :func:`segment_sum_gathered`.  Both paths are exact:
-    min and max do not depend on the order of the rows."""
+    Traceable, like :func:`segment_sum_gathered`, and paged as it is.  Both
+    paths are exact: min and max do not depend on the order of the rows."""
     interpret = _default_interpret() if interpret is None else interpret
     squeeze = gathered.ndim == 1
     v = (gathered[:, None] if squeeze else gathered).astype(jnp.float32)

@@ -29,6 +29,29 @@ sublanes, segment ids on lanes, so the masked ``[TM, TS]`` tile reduces
 over sublanes with elementwise VPU min/max (a cross-lane reduce would go
 through the XLU for every segment).  Same grid, same scalar-prefetch
 tables, same revisit accumulation into the resident ``[D, TS]`` block.
+
+Pages.  Both kernels prefetch the plan's two int32 tile tables
+(``m2out``, ``first_visit``) into scalar memory, 8 bytes a tile, and
+v5e's SMEM is 1 MiB a core: one call over a whole plan stops compiling
+near 131,000 tiles.  A plan of more than :data:`PAGE_TILES` tiles is
+therefore reduced in *pages*: static pieces of ``PAGE_TILES`` tiles, cut
+at multiples of ``PAGE_TILES`` (the tile count is known at trace time, so
+a patched plan keeps its compile), each one ``pallas_call`` over its own
+slices of the two tables.  The output runs from page to page through
+``input_output_aliases``; a page whose first tile continues an output
+tile begun on the page before (``first_visit == 0`` at its tile 0) reads
+that block back through an extra input mapped like the output and
+combines (``+``, ``min`` or ``max``) before it goes on, so sums
+accumulate in exactly the order of one call and every result is
+bit-identical.  The last page is padded to ``PAGE_TILES`` tiles marked
+``SKIP_TILE`` (no work, block index held), so every page has one shape
+and one Mosaic body; the rows and seg ids are never copied, each page
+indexes them at its own offset (a third, one-word prefetched table).  A
+plan of at most ``PAGE_TILES`` tiles takes one page: the one call above,
+lowered exactly as before paging.  (The paged body over such a plan, as
+one page, is bit-identical but 3.5% slower for the sum and 4.4% for min
+and max at 57,859 tiles on a TPU v5e: its carry input and skip test cost
+in every grid step.)
 """
 
 from __future__ import annotations
@@ -44,26 +67,46 @@ from jax.experimental.pallas import tpu as pltpu
 DEFAULT_TM = 512  # rows per input tile
 DEFAULT_TS = 512  # segment ids per output tile
 
+# Tiles whose tables one pallas_call prefetches into scalar memory.  v5e
+# has 1 MiB of SMEM a core; the two int32 tables take 8 bytes a tile, so
+# 2**16 tiles hold 512 KiB of tables, half of SMEM, and leave the other
+# half to the compiler's own scalars.  (60,038 tiles, 480 KB, compile on
+# the chip; 180,956 tiles, 1.38 MB, do not.)  A constant, not a knob.
+PAGE_TILES = 1 << 16
+# first_visit value of a padding tile on the last page: no work
+SKIP_TILE = 2
 
-def _seg_sum_kernel(m2out_ref, first_ref, seg_ref, vals_ref, out_ref, *, ts: int):
-    mi = pl.program_id(0)
-    seg = seg_ref[...]  # [1, TM] int32 (padding rows carry -1)
-    vals = vals_ref[...]  # [D, TM]
-    tm = seg.shape[1]
-    rel = seg - m2out_ref[mi] * ts
+
+def num_pages(num_m_tiles: int) -> int:
+    """Pages (pallas_calls) a plan of ``num_m_tiles`` tiles is reduced in
+    by each segment kernel: 1 up to :data:`PAGE_TILES` tiles."""
+    return max(1, -(-int(num_m_tiles) // PAGE_TILES))
+
+
+def _sum_partial(rel, vals, ts: int):
+    """[D, TS] sums of one tile: ``rel`` [1, TM] seg ids relative to the
+    output tile (padding rows < 0), ``vals`` [D, TM]."""
+    tm = rel.shape[1]
     # transposed one-hot [TS, TM] built on the fly from the seg-id row: a
     # padding row or a row outside this output tile matches no iota value
     iota = jax.lax.broadcasted_iota(jnp.int32, (ts, tm), 0)
     oh_t = (iota == rel).astype(vals.dtype)
     # HIGHEST keeps the f32 values exact through the MXU (a one-pass bf16
     # matmul would round them to 8 mantissa bits)
-    partial = jax.lax.dot_general(
+    return jax.lax.dot_general(
         vals,
         oh_t,
         (((1,), (1,)), ((), ())),  # contract over TM: [D, TS]
         precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32,
     )
+
+
+def _seg_sum_kernel(m2out_ref, first_ref, seg_ref, vals_ref, out_ref, *, ts: int):
+    mi = pl.program_id(0)
+    seg = seg_ref[...]  # [1, TM] int32 (padding rows carry -1)
+    vals = vals_ref[...]  # [D, TM]
+    partial = _sum_partial(seg - m2out_ref[mi] * ts, vals, ts)
 
     @pl.when(first_ref[mi] == 1)
     def _init():
@@ -72,6 +115,108 @@ def _seg_sum_kernel(m2out_ref, first_ref, seg_ref, vals_ref, out_ref, *, ts: int
     @pl.when(first_ref[mi] == 0)
     def _acc():
         out_ref[...] = out_ref[...] + partial.astype(out_ref.dtype)
+
+
+def _compiler_params():
+    return pltpu.CompilerParams(dimension_semantics=(pltpu.ARBITRARY,))
+
+
+def _one_call(kernel, vals, seg_ids, m2out, first_visit, num_out_tiles: int,
+              tm: int, ts: int, interpret: bool):
+    """One pallas_call over a whole plan of at most PAGE_TILES tiles."""
+    num_m_tiles = seg_ids.shape[0]
+    d = vals.shape[0]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,  # m2out, first_visit
+        grid=(num_m_tiles,),
+        in_specs=[
+            # one [1, TM] seg-id row per tile: the last two block dims equal
+            # the array's, as the TPU lowering requires
+            pl.BlockSpec((None, 1, tm), lambda mi, m2out, first: (mi, 0, 0)),
+            pl.BlockSpec((d, tm), lambda mi, m2out, first: (0, mi)),
+        ],
+        out_specs=pl.BlockSpec((d, ts), lambda mi, m2out, first: (0, m2out[mi])),
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((d, num_out_tiles * ts), jnp.float32),
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+    )(m2out, first_visit, seg_ids.reshape(num_m_tiles, 1, tm), vals)
+
+
+def _page_kernel(base_ref, m2out_ref, first_ref, seg_ref, vals_ref, prev_ref,
+                 out_ref, *, ts: int, partial_fn, combine):
+    """One page of a paged reduce: the one-call kernel's init and revisit
+    accumulation, plus the carry of an output tile begun on the page
+    before (``prev_ref``, that block as the page before left it)."""
+    del base_ref  # read by the index maps only
+    mi = pl.program_id(0)
+    first = first_ref[mi]
+
+    @pl.when(first != SKIP_TILE)
+    def _tile():
+        partial = partial_fn(seg_ref[...] - m2out_ref[mi] * ts, vals_ref[...])
+
+        @pl.when(first == 1)
+        def _init():
+            out_ref[...] = partial
+
+        @pl.when((first == 0) & (mi == 0))
+        def _carry():
+            out_ref[...] = combine(prev_ref[...], partial)
+
+        @pl.when((first == 0) & (mi > 0))
+        def _acc():
+            out_ref[...] = combine(out_ref[...], partial)
+
+
+def _paged_calls(partial_fn, combine, vals, seg_ids, m2out, first_visit,
+                 num_out_tiles: int, tm: int, ts: int, interpret: bool):
+    """``num_pages`` pallas_calls of PAGE_TILES tiles each, cut at
+    multiples of PAGE_TILES, the output aliased from one to the next."""
+    num_m_tiles = seg_ids.shape[0]
+    d = vals.shape[0]
+    page = PAGE_TILES
+    pad = num_pages(num_m_tiles) * page - num_m_tiles
+    # padding tiles hold the last output block and do no work
+    m2out = jnp.concatenate([m2out, jnp.broadcast_to(m2out[-1:], (pad,))])
+    first_visit = jnp.concatenate(
+        [first_visit, jnp.full((pad,), SKIP_TILE, first_visit.dtype)])
+    last = num_m_tiles - 1
+
+    def tile(mi, base):  # the plan's tile at the page's step mi
+        return jnp.minimum(base[0] + mi, last)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,  # the page's first tile, m2out, first_visit
+        grid=(page,),
+        in_specs=[
+            pl.BlockSpec((None, 1, tm),
+                         lambda mi, base, m2o, fv: (tile(mi, base), 0, 0)),
+            pl.BlockSpec((d, tm),
+                         lambda mi, base, m2o, fv: (0, tile(mi, base))),
+            # the output block the page starts in: read once, at step 0
+            pl.BlockSpec((d, ts), lambda mi, base, m2o, fv: (0, m2o[0])),
+        ],
+        out_specs=pl.BlockSpec((d, ts), lambda mi, base, m2o, fv: (0, m2o[mi])),
+    )
+    call = pl.pallas_call(
+        functools.partial(_page_kernel, ts=ts, partial_fn=partial_fn,
+                          combine=combine),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((d, num_out_tiles * ts), jnp.float32),
+        input_output_aliases={5: 0},  # prev -> out
+        compiler_params=_compiler_params(),
+        interpret=interpret,
+    )
+    seg_ids = seg_ids.reshape(num_m_tiles, 1, tm)
+    out = jnp.zeros((d, num_out_tiles * ts), jnp.float32)
+    for lo in range(0, num_m_tiles, page):
+        out = call(jnp.full((1,), lo, jnp.int32), m2out[lo:lo + page],
+                   first_visit[lo:lo + page], seg_ids, vals, out)
+    return out
 
 
 @functools.partial(
@@ -88,37 +233,30 @@ def segment_sum_tiled(
     ts: int = DEFAULT_TS,
     interpret: bool = False,
 ):
-    """Returns [D, num_out_tiles * TS] f32 segment sums."""
+    """Returns [D, num_out_tiles * TS] f32 segment sums: one pallas_call up
+    to PAGE_TILES tiles, else ``num_pages`` calls of PAGE_TILES tiles (the
+    module docstring), bit-identical to one call."""
     num_m_tiles = seg_ids.shape[0]
-    d = vals.shape[0]
     assert vals.shape[1] == num_m_tiles * tm, (vals.shape, num_m_tiles, tm)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # m2out, first_visit
-        grid=(num_m_tiles,),
-        in_specs=[
-            # one [1, TM] seg-id row per tile: the last two block dims equal
-            # the array's, as the TPU lowering requires
-            pl.BlockSpec((None, 1, tm), lambda mi, m2out, first: (mi, 0, 0)),
-            pl.BlockSpec((d, tm), lambda mi, m2out, first: (0, mi)),
-        ],
-        out_specs=pl.BlockSpec((d, ts), lambda mi, m2out, first: (0, m2out[mi])),
-    )
-    return pl.pallas_call(
-        functools.partial(_seg_sum_kernel, ts=ts),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((d, num_out_tiles * ts), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(pltpu.ARBITRARY,)
-        ),
-        interpret=interpret,
-    )(m2out, first_visit, seg_ids.reshape(num_m_tiles, 1, tm), vals)
+    args = (vals, seg_ids, m2out, first_visit, num_out_tiles, tm, ts,
+            interpret)
+    if num_m_tiles <= PAGE_TILES:
+        return _one_call(functools.partial(_seg_sum_kernel, ts=ts), *args)
+    return _paged_calls(functools.partial(_sum_partial, ts=ts), jnp.add,
+                        *args)
 
 
-def _seg_minmax_kernel(m2out_ref, first_ref, seg_ref, vals_ref, out_ref, *,
-                       ts: int, op: str):
-    mi = pl.program_id(0)
-    rel = seg_ref[...] - m2out_ref[mi] * ts  # [1, TM], padding rows < 0
-    vals = vals_ref[...]  # [D, TM]
+def _minmax_ops(op: str):
+    """``(identity, reduce, combine)`` of ``op``."""
+    if op == "min":
+        return jnp.inf, jnp.min, jnp.minimum
+    return -jnp.inf, jnp.max, jnp.maximum
+
+
+def _minmax_partial(rel, vals, ts: int, op: str):
+    """[D, TS] minima or maxima of one tile (a segment with no row in it
+    holds the identity): ``rel`` [1, TM] seg ids relative to the output
+    tile (padding rows < 0), ``vals`` [D, TM]."""
     d, tm = vals.shape
     # rows onto sublanes: Mosaic transposes whole (8, 128) tiles, so the
     # seg-id row is broadcast to 8 sublanes and the channels are padded to
@@ -130,14 +268,19 @@ def _seg_minmax_kernel(m2out_ref, first_ref, seg_ref, vals_ref, out_ref, *,
     rows = jnp.transpose(vals)  # [TM, dp]
     # a padding row or a row outside this output tile matches no lane
     hit = jax.lax.broadcasted_iota(jnp.int32, (tm, ts), 1) == rel_col
-    if op == "min":
-        ident, reduce, combine = jnp.inf, jnp.min, jnp.minimum
-    else:
-        ident, reduce, combine = -jnp.inf, jnp.max, jnp.maximum
-    partial = jnp.concatenate([
+    ident, reduce, _ = _minmax_ops(op)
+    return jnp.concatenate([
         reduce(jnp.where(hit, rows[:, c:c + 1], ident), axis=0, keepdims=True)
         for c in range(d)
-    ])  # [D, TS]; a segment with no row in this tile holds the identity
+    ])
+
+
+def _seg_minmax_kernel(m2out_ref, first_ref, seg_ref, vals_ref, out_ref, *,
+                       ts: int, op: str):
+    mi = pl.program_id(0)
+    rel = seg_ref[...] - m2out_ref[mi] * ts  # [1, TM], padding rows < 0
+    partial = _minmax_partial(rel, vals_ref[...], ts, op)
+    combine = _minmax_ops(op)[2]
 
     @pl.when(first_ref[mi] == 1)
     def _init():
@@ -164,27 +307,17 @@ def segment_minmax_tiled(
     interpret: bool = False,
 ):
     """Returns [D, num_out_tiles * TS] f32 segment minima (``op="min"``) or
-    maxima (``op="max"``); a segment with no rows holds +inf / -inf."""
+    maxima (``op="max"``); a segment with no rows holds +inf / -inf.  One
+    pallas_call up to PAGE_TILES tiles, else ``num_pages`` calls of
+    PAGE_TILES tiles, as :func:`segment_sum_tiled`."""
     if op not in ("min", "max"):
         raise ValueError(f"op must be 'min' or 'max', not {op!r}")
     num_m_tiles = seg_ids.shape[0]
-    d = vals.shape[0]
     assert vals.shape[1] == num_m_tiles * tm, (vals.shape, num_m_tiles, tm)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # m2out, first_visit
-        grid=(num_m_tiles,),
-        in_specs=[
-            pl.BlockSpec((None, 1, tm), lambda mi, m2out, first: (mi, 0, 0)),
-            pl.BlockSpec((d, tm), lambda mi, m2out, first: (0, mi)),
-        ],
-        out_specs=pl.BlockSpec((d, ts), lambda mi, m2out, first: (0, m2out[mi])),
-    )
-    return pl.pallas_call(
-        functools.partial(_seg_minmax_kernel, ts=ts, op=op),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((d, num_out_tiles * ts), jnp.float32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=(pltpu.ARBITRARY,)
-        ),
-        interpret=interpret,
-    )(m2out, first_visit, seg_ids.reshape(num_m_tiles, 1, tm), vals)
+    args = (vals, seg_ids, m2out, first_visit, num_out_tiles, tm, ts,
+            interpret)
+    if num_m_tiles <= PAGE_TILES:
+        return _one_call(functools.partial(_seg_minmax_kernel, ts=ts, op=op),
+                         *args)
+    return _paged_calls(functools.partial(_minmax_partial, ts=ts, op=op),
+                        _minmax_ops(op)[2], *args)

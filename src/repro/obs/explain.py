@@ -321,10 +321,16 @@ def _plan_anatomy(plan, index, use_pallas: bool
     is the session's, which with the plan decides the min/max route."""
     if plan is None:
         return None, {}, {}
-    from repro.core.engine_jax import minmax_route  # loaded with any plan
+    from repro.core.engine_jax import (  # loaded with any plan
+        minmax_route,
+        plan_pages,
+    )
 
     cls = type(plan).__name__
     route = minmax_route(plan, use_pallas)
+    # Pallas calls per segment kernel over the largest pass (None where
+    # the XLA segment ops run)
+    pages = plan_pages(plan) if use_pallas else None
     if cls == "DBIndexPlan":
         real1 = int(index.block_members.size) if index is not None else None
         real2 = int(index.link_block.size) if index is not None else None
@@ -346,6 +352,7 @@ def _plan_anatomy(plan, index, use_pallas: bool
                              if plan.p2_ell is not None else None),
             },
             "minmax_route": route,
+            "pages": pages,
         }
         if real1 is not None:
             anat["pass1_rows_real"] = real1
@@ -363,6 +370,7 @@ def _plan_anatomy(plan, index, use_pallas: bool
             "wd_tile_groups": int(plan.wd_plan.num_out_tiles),
             "tile": {"tm": int(plan.wd_plan.tm), "ts": int(plan.wd_plan.ts)},
             "minmax_route": route,
+            "pages": pages,
         }
         if real is not None:
             anat["wd_rows_real"] = real

@@ -186,6 +186,9 @@ class AffectedOwnerCache:
         self.misses = 0
         self.invalidated = 0  # per-vertex invalidations applied
         self.full_drops = 0  # whole entries dropped (stateless groups)
+        # owners the last update invalidated in the group with the most
+        # (every vertex where it dropped a group wholesale)
+        self.last_owners = 0
         obs = obs if obs is not None else _obs.get_registry()
         self._m_events = obs.counter(
             "repro_cache_events_total",
@@ -237,6 +240,7 @@ class AffectedOwnerCache:
         """Advance to ``version``.  ``owner_map[gi]`` is the group's
         affected-owner array, or None when the group has no incremental
         state (nothing bounds its staleness — drop the entry).
+        :attr:`last_owners` keeps the largest group's count.
 
         The version advances *first*: a concurrent reader that computed a
         group vector at the old version must find its ``put_group``
@@ -247,11 +251,15 @@ class AffectedOwnerCache:
         after this returns.
         """
         self.version = version
+        self.last_owners = 0
         for gi, owners in owner_map.items():
             e = self._entries.get(gi)
+            if owners is not None:
+                self.last_owners = max(self.last_owners, int(np.size(owners)))
             if e is None:
                 continue
             if owners is None:
+                self.last_owners = max(self.last_owners, e["valid"].size)
                 del self._entries[gi]
                 self.full_drops += 1
                 self._m_events.labels("drop").inc()
@@ -506,6 +514,12 @@ class WindowService:
         # corrupt another caller's answer
         return (vec[vertex] if vertex is not None else vec.copy()), False
 
+    def _cache_answers(self, view, gi: int, agg: str, vertex: int) -> bool:
+        """Whether the cache holds the point read's answer at the view's
+        version (a look that counts nothing)."""
+        return (self.cache is not None and self.cache.get_point(
+            gi, agg, vertex, view.version) is not None)
+
     def _take_pending(self) -> List[Ticket]:
         """Atomically detach the queue (so a raise can never strand it),
         stamping each ticket with the time it left the queue."""
@@ -530,31 +544,43 @@ class WindowService:
         ``reason`` labels the flush trigger in the metrics: "manual" here,
         "fill"/"deadline" when the continuous-batching front end decides.
         """
-        with self._flush_lock:
-            return self._serve(self._take_pending(), reason)
+        return self._flush(reason)
 
-    def _serve(self, pending: List[Ticket],
-               reason: str = "manual") -> List[Ticket]:
+    def _flush(self, reason: str, refresh=None) -> List[Ticket]:
+        with self._flush_lock:
+            return self._serve(self._take_pending(), reason, refresh)
+
+    def _serve(self, pending: List[Ticket], reason: str = "manual",
+               refresh=None) -> List[Ticket]:
         if not pending:
             return pending
         with self.tracer.span("flush", cat="serve", reason=reason,
                               pending=len(pending)) as span:
-            return self._serve_inner(pending, reason, span.id)
+            return self._serve_inner(pending, reason, span.id, refresh)
 
     def _serve_inner(self, pending: List[Ticket], reason: str,
-                     flush_id: Optional[int] = None) -> List[Ticket]:
+                     flush_id: Optional[int] = None,
+                     refresh=None) -> List[Ticket]:
+        """Serve ``pending`` at the active view.  ``refresh``, where given,
+        takes the point reads the cache cannot answer (with the flush's
+        reason and span), which then finish after this flush returns."""
         view = self._active
         groups = self.session.compiled.groups
         slots = self.session.compiled.spec_slots
         by_group: Dict[int, List[Ticket]] = {}
         memo: Dict[int, object] = {}  # group vectors (or poison) this flush
+        later: List[Ticket] = []
         for t in pending:
             gi, ai = slots[t.spec_index]
             if t.values is None:
+                agg = groups[gi].aggs[ai]
+                if (refresh is not None and t.vertex is not None
+                        and not self._cache_answers(view, gi, agg, t.vertex)):
+                    later.append(t)
+                    continue
                 try:
                     t.result, t.cache_hit = self._serve_snapshot(
-                        view, gi, groups[gi].aggs[ai], t.vertex, memo
-                    )
+                        view, gi, agg, t.vertex, memo)
                     t.version = view.version
                 except BaseException as e:
                     t.error = e
@@ -599,9 +625,29 @@ class WindowService:
                     t.result = (vec[t.vertex] if t.vertex is not None
                                 else np.asarray(vec))
                     t.version = view.version
+        if later:
+            refresh(later, reason, flush_id)
+            left = {id(t) for t in later}
+            served = [t for t in pending if id(t) not in left]
+        else:
+            served = pending
+        ok = self._complete(view, served, reason, flush_id)
+        self.flushes += 1
+        self._m_flushes.labels(reason).inc()
+        self._m_flush_size.observe(len(pending))
+        self.flight.record("flush", reason=reason, tickets=len(pending),
+                           served=ok, failed=len(served) - ok,
+                           version=view.version)
+        return pending
+
+    def _complete(self, view, tickets: List[Ticket], reason: str,
+                  flush_id: Optional[int]) -> int:
+        """Finish ``tickets``, served at ``view`` by the flush ``flush_id``
+        (for ``reason``): latency, SLO, span, waiter; returns how many
+        succeeded."""
         now = self.now()
         ok = 0
-        for t in pending:
+        for t in tickets:
             t.latency_s = now - t.submitted_s
             if t.error is None:
                 ok += 1
@@ -618,23 +664,18 @@ class WindowService:
                         queued_ms=(t.detached_s - t.submitted_s) * 1e3)
                 t._span.finish(parent=flush_id)
             t._finish()
-        self.flushes += 1
-        self.served += ok
-        self.failed += len(pending) - ok
-        self._m_flushes.labels(reason).inc()
-        self._m_flush_size.observe(len(pending))
-        self.flight.record("flush", reason=reason, tickets=len(pending),
-                           served=ok, failed=len(pending) - ok,
-                           version=view.version)
+        with self._lock:
+            self.served += ok
+            self.failed += len(tickets) - ok
         if self.auditor is not None:
             try:
-                self.auditor.observe_flush(view, pending)
+                self.auditor.observe_flush(view, tickets)
             except Exception:
                 pass  # auditing is evidence, never a serving failure
-        if ok < len(pending):
-            self._on_ticket_failure([t for t in pending
+        if ok < len(tickets):
+            self._on_ticket_failure([t for t in tickets
                                      if t.error is not None])
-        return pending
+        return ok
 
     # ------------------------------------------------------------------ #
     def _on_ticket_failure(self, failed: List[Ticket]) -> None:
@@ -686,7 +727,7 @@ class WindowService:
         a reader still pinned behind the head simply bypasses the cache
         rather than ever seeing version-v+1 data at version v.
         """
-        with self.tracer.span("service.update", cat="update"):
+        with self.tracer.span("service.update", cat="update") as span:
             reports = self.session.update(batch)
             for key, rep in reports.items():
                 self.flight.record(
@@ -695,6 +736,8 @@ class WindowService:
                     plan_version=rep.get("plan_version"),
                     affected=int(np.size(rep.get("affected_owners", ()))),
                     reorganized=bool(rep.get("reorganized", False)))
+            if self.cache is not None:
+                span.set(owners=self.cache.last_owners)
             if self.auto_flip:
                 self.flip()
         self._m_updates.inc()
@@ -746,6 +789,13 @@ class AsyncWindowService(WindowService):
       the earliest ticket's per-class deadline (``max_delay_ms``) expires
       (deadline flush).  At low load this bounds p99 latency by the
       deadline instead of by "whenever the bucket happens to fill".
+
+    * **Refreshes apart** — a point read the affected-owner cache cannot
+      answer waits for a whole-group refresh, which a daemon refresher
+      runs (one group at a time, at the newest published view), so the
+      flusher goes on serving cache hits meanwhile: after a write, only
+      the reads of owners it invalidated wait for the refresh.  Misses
+      that arrive during a refresh are taken by the next one.
 
     * **Backpressure + load shedding** — when the queue reaches the
       admission window, the lowest-priority *sheddable full-graph scan*
@@ -813,6 +863,12 @@ class AsyncWindowService(WindowService):
         self._thread: Optional[threading.Thread] = None
         self._stopping = False
         self._drain = True
+        # point reads waiting for a refresh, by plan group, each with the
+        # reason and span of the flush that took it
+        self._refresh_cv = threading.Condition(threading.Lock())
+        self._refresh_due: Dict[int, List[tuple]] = {}
+        self._refresher: Optional[threading.Thread] = None
+        self._refresher_stopping = False
         # telemetry
         self.shed = 0
         self.deadline_flushes = 0
@@ -836,7 +892,11 @@ class AsyncWindowService(WindowService):
     def start(self) -> "AsyncWindowService":
         if self.running:
             return self
-        self._stopping = False
+        self._stopping = self._refresher_stopping = False
+        self._refresher = threading.Thread(target=self._refresher_loop,
+                                           name="window-service-refresher",
+                                           daemon=True)
+        self._refresher.start()
         self._thread = threading.Thread(target=self._flusher_loop,
                                         name="window-service-flusher",
                                         daemon=True)
@@ -855,6 +915,12 @@ class AsyncWindowService(WindowService):
             self._cv.notify_all()
         self._thread.join(timeout=30)
         self._thread = None
+        # reads a flush already took are served either way
+        with self._refresh_cv:
+            self._refresher_stopping = True
+            self._refresh_cv.notify_all()
+        self._refresher.join()
+        self._refresher = None
         if drain:
             self.flush()
         else:
@@ -995,8 +1061,8 @@ class AsyncWindowService(WindowService):
         # runaway controller from busy-flushing every submit
         return min(max(eff, 0.05), declared) / 1e3
 
-    def flush(self, reason: str = "manual") -> List[Ticket]:
-        served = super().flush(reason)
+    def _flush(self, reason: str, refresh=None) -> List[Ticket]:
+        served = super()._flush(reason, refresh)
         with self._cv:
             self._g_pending.set(len(self._pending))
             self._cv.notify_all()  # release backpressure waiters
@@ -1034,12 +1100,12 @@ class AsyncWindowService(WindowService):
             return []
         return self._flush_reason(reason)
 
-    def _flush_reason(self, reason: str) -> List[Ticket]:
+    def _flush_reason(self, reason: str, refresh=None) -> List[Ticket]:
         if reason == "fill":
             self.fill_flushes += 1
         else:
             self.deadline_flushes += 1
-        return self.flush(reason)
+        return self._flush(reason, refresh)
 
     def _flusher_loop(self) -> None:
         self.tracer.name_thread()
@@ -1049,7 +1115,7 @@ class AsyncWindowService(WindowService):
             if reason is None:
                 return  # stop() drains (or fails) the leftovers
             try:
-                self._flush_reason(reason)
+                self._flush_reason(reason, self._refresh_later)
             except Exception:
                 # _serve records per-ticket errors; anything escaping here
                 # is a bug in the scheduler itself — keep the loop alive,
@@ -1081,6 +1147,60 @@ class AsyncWindowService(WindowService):
         finally:
             if wait is not None:
                 wait.__exit__(None, None, None)
+
+    # --------------------------- refreshes ---------------------------- #
+    def _refresh_later(self, tickets: List[Ticket], reason: str,
+                       flush_id: Optional[int]) -> None:
+        """Hand point reads the cache missed to the refresher."""
+        slots = self.session.compiled.spec_slots
+        with self._refresh_cv:
+            for t in tickets:
+                gi = slots[t.spec_index][0]
+                self._refresh_due.setdefault(gi, []).append(
+                    (t, reason, flush_id))
+            self._refresh_cv.notify_all()
+
+    def _refresher_loop(self) -> None:
+        """Serve the waiting reads of one group at a time with one group
+        read at the newest published view (never older than the view the
+        flush missed at, so read-after-ack holds); once the service
+        stops, return when nothing waits."""
+        self.tracer.name_thread()
+        while True:
+            with self._refresh_cv:
+                while not (self._refresh_due or self._refresher_stopping):
+                    self._refresh_cv.wait()
+                if not self._refresh_due:
+                    return
+                gi = next(iter(self._refresh_due))
+                waiting = self._refresh_due.pop(gi)
+            try:
+                self._refresh(gi, waiting)
+            except Exception:
+                pass  # as in the flusher: keep serving later groups
+
+    def _refresh(self, gi: int, waiting: List[tuple]) -> None:
+        """One group read for ``waiting`` (tickets with their flush's
+        reason and span), then finish them."""
+        groups = self.session.compiled.groups
+        slots = self.session.compiled.spec_slots
+        view = self._active
+        memo: Dict[int, object] = {}
+        with self.tracer.span("refresh", cat="serve", group=gi,
+                              tickets=len(waiting)):
+            for t, _, _ in waiting:
+                try:
+                    t.result, t.cache_hit = self._serve_snapshot(
+                        view, gi, groups[gi].aggs[slots[t.spec_index][1]],
+                        t.vertex, memo)
+                    t.version = view.version
+                except BaseException as e:
+                    t.error = e
+        by_flush: Dict[tuple, List[Ticket]] = {}
+        for t, reason, flush_id in waiting:
+            by_flush.setdefault((reason, flush_id), []).append(t)
+        for (reason, flush_id), tickets in by_flush.items():
+            self._complete(view, tickets, reason, flush_id)
 
     # --------------------------- durability --------------------------- #
     def update(self, batch) -> Dict:

@@ -214,6 +214,45 @@ def test_spans_name_the_minmax_route():
     assert routes["executor.device"] == ["xla", "ell"]
 
 
+def test_spans_count_the_kernel_pages(monkeypatch):
+    """``query.group`` and ``executor.device`` carry ``pages``, the Pallas
+    calls each segment kernel takes over the plan's largest pass: 1 under
+    ``PAGE_TILES``, more once the plan has more tiles; absent on the XLA
+    route.  Paged answers equal the one-call answers."""
+    from repro.kernels.segment_reduce import segment_reduce as sr
+    from repro.obs.tracing import Tracer
+
+    g, _ = _hub_tree_plan()
+    vb = np.stack([g.attrs["val"]] * 2)
+
+    def pages_of(use_pallas, page_tiles=None):
+        if page_tiles is not None:
+            monkeypatch.setattr(sr, "PAGE_TILES", page_tiles)
+        jax.clear_caches()
+        tr = Tracer()
+        sess = Session(g, [QuerySpec(("khop", 2), "max")], device=True,
+                       use_pallas=use_pallas, tracer=tr)
+        out = sess.run(), sess.snapshot().run_many(vb)
+        spans = {e["name"]: e["args"].get("pages") for e in tr.events()
+                 if e["name"] in ("query.group", "executor.device")}
+        return spans, out, sess.snapshot().artifacts[0][0][1]
+
+    try:
+        one, ref, plan = pages_of(True)
+        assert one == {"query.group": 1, "executor.device": 1}
+        assert pages_of(False)[0] == {"query.group": None,
+                                      "executor.device": None}
+        paged, got, _ = pages_of(True, page_tiles=2)
+        want = -(-max(plan.pass1.seg_tiles.shape[0],
+                      plan.pass2.seg_tiles.shape[0]) // 2)
+        assert paged == {"query.group": want, "executor.device": want} \
+            and want > 1
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(ref)):
+            np.testing.assert_array_equal(a, b)
+    finally:
+        jax.clear_caches()
+
+
 @pytest.mark.parametrize("schedule", ["level", "doubling"])
 def test_fused_iindex_multi_all_monoids(schedule, topo_case):
     g, w, refs = topo_case

@@ -477,3 +477,84 @@ def test_flusher_wait_span_carries_the_reason_it_woke():
     waits = [e for e in tr.events() if e["name"] == "flush.wait"]
     assert waits and waits[0]["args"]["reason"] == "deadline"
     assert waits[0]["dur"] > 0
+
+
+# ---------------------------------------------------------------------- #
+#  Refreshes apart from the flusher
+# ---------------------------------------------------------------------- #
+def _after_one_write(seed):
+    """A started service with its cache filled, then one value write: the
+    write's version, a vertex it invalidated and one it left valid, and
+    the sum oracle after the write."""
+    g, specs, sess = make_session(seed=seed)
+    svc = AsyncWindowService(sess, bucket=64).start()
+    svc.submit(0, vertex=0).get(timeout=10.0)  # fills the cache
+    vals = np.asarray(g.attrs["val"], np.float64).copy()
+    hub = int(np.argmax(np.bincount(g.src, minlength=g.n)))
+    vals[hub] += 7
+    svc.update(UpdateBatch.attr_set("val", [hub], [vals[hub]]))
+    valid = svc.cache._entries[0]["valid"]
+    stale, fresh = int(np.flatnonzero(~valid)[0]), int(np.flatnonzero(valid)[0])
+    oracle = brute_force(g, KHopWindow(2), vals, "sum", dtype=np.float32)
+    return svc, svc.version, stale, fresh, oracle
+
+
+def test_cache_hits_do_not_wait_for_a_refresh(monkeypatch):
+    """While the refresher is held inside a whole-group refresh, a read the
+    cache answers is served; the read that missed gets the refreshed
+    answer, exact at the write's version or later."""
+    svc, version, stale, fresh, oracle = _after_one_write(51)
+    entered, release = threading.Event(), threading.Event()
+    real = api.SessionView.run_group
+
+    def held(self, gi, values=None):
+        entered.set()
+        assert release.wait(10.0)
+        return real(self, gi, values)
+
+    monkeypatch.setattr(api.SessionView, "run_group", held)
+    try:
+        miss = svc.submit(0, vertex=stale)
+        assert entered.wait(10.0)
+        hit = svc.submit(0, vertex=fresh)
+        assert hit.get(timeout=10.0) == oracle[fresh] and hit.cache_hit
+        assert not miss.done
+    finally:
+        release.set()
+    assert miss.get(timeout=10.0) == oracle[stale] and not miss.cache_hit
+    assert miss.version >= version and hit.version == version
+    svc.stop()
+    assert svc.stats["served"] == 3 and svc.stats["failed"] == 0
+
+
+def test_stop_serves_reads_waiting_for_a_refresh(monkeypatch):
+    """Reads a flush handed to the refresher are served by ``stop``, with
+    or without drain, and a failed refresh fails exactly them."""
+    for drain in (True, False):
+        svc, _, stale, _, oracle = _after_one_write(53)
+        entered, release = threading.Event(), threading.Event()
+        real = api.SessionView.run_group
+
+        def held(self, gi, values=None):
+            entered.set()
+            assert release.wait(10.0)
+            return real(self, gi, values)
+
+        monkeypatch.setattr(api.SessionView, "run_group", held)
+        t = svc.submit(0, vertex=stale)
+        assert entered.wait(10.0)  # the refresher holds it
+        threading.Timer(0.2, release.set).start()
+        svc.stop(drain=drain)
+        assert t.done and t.result == oracle[stale]
+        monkeypatch.undo()
+    svc, _, stale, fresh, _ = _after_one_write(55)
+    monkeypatch.setattr(
+        api.SessionView, "run_group",
+        lambda self, gi, values=None:
+            (_ for _ in ()).throw(RuntimeError("boom")))
+    bad = svc.submit(0, vertex=stale)
+    with pytest.raises(RuntimeError):
+        bad.get(timeout=10.0)
+    assert svc.submit(0, vertex=fresh).get(timeout=10.0) is not None
+    svc.stop()
+    assert svc.stats["failed"] == 1

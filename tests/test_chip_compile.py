@@ -159,6 +159,74 @@ def test_batched_executor_without_ell_runs_tiled_minmax_on_v5e(one_chip):
     assert _has_minmax_kernel(_batched_executor_text("jax", plan, n, one_chip))
 
 
+def _prefetch_sizes(fn, *args):
+    """Per pallas_call in ``fn``'s jaxpr (nested ones included), the sizes
+    of its scalar-prefetch operands."""
+    out = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                k = eqn.params["grid_mapping"].num_index_operands
+                out.append([int(np.prod(v.aval.shape))
+                            for v in eqn.invars[:k]])
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return out
+
+
+def _abstract_dbplan(one_chip, tiles1, tiles2, n=32768, cap=262144):
+    """A DBIndex plan of shapes only: ``tiles1`` pass-1 and ``tiles2``
+    pass-2 tiles of 512 rows, no ELL layout."""
+    from repro.core import engine_jax as ej
+    from repro.kernels.segment_reduce.ops import TilePlan
+
+    def s(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def tiles(nm, segments):
+        i32 = jnp.int32
+        return TilePlan(s((nm * 512,), i32), s((nm, 512), i32), s((nm,), i32),
+                        s((nm,), i32), segments, -(-segments // 512), 512, 512)
+
+    return ej.DBIndexPlan(
+        n=n, num_blocks=s((), jnp.int32), block_capacity=cap,
+        pass1=tiles(tiles1, cap), pass2=tiles(tiles2, n),
+        block_sizes=s((cap,), jnp.float32), link_counts=s((n,), jnp.float32))
+
+
+@pytest.mark.parametrize("tiles1", [60_038, 261_247])
+def test_batched_executor_pages_large_plans_on_v5e(tiles1, one_chip):
+    """The [8, n] launch over a plan of more than ``PAGE_TILES`` tiles (the
+    ``graph500-kron`` plan's 261,247 pass-1 tiles) compiles for v5e, and no
+    pallas_call in it prefetches the tables of more than ``PAGE_TILES``
+    tiles; a plan of at most ``PAGE_TILES`` tiles (the ``khop2-lj``
+    plan's 60,038) takes one call per kernel use, as before paging."""
+    from repro.core.api import _get_vmany
+    from repro.kernels.segment_reduce.segment_reduce import PAGE_TILES
+
+    plan = _abstract_dbplan(one_chip, tiles1, 41_990)
+    vb = jax.ShapeDtypeStruct((8, plan.n), jnp.float32, sharding=one_chip)
+    fn = _get_vmany("jax")
+    sizes = _prefetch_sizes(
+        lambda p, v: fn(p, v, AGGS, True, False), plan, vb)
+    pages = plan.pass1.pages
+    # pass 1: the value sum, min and max; pass 2: the stacked sums, min, max
+    assert len(sizes) == 3 * pages + 3
+    assert max(max(s) for s in sizes) <= PAGE_TILES
+    if tiles1 <= PAGE_TILES:
+        assert pages == 1
+        assert sorted(sizes) == [[41_990] * 2] * 3 + [[tiles1] * 2] * 3
+    else:
+        assert pages == 4
+    text = fn.lower(plan, vb, AGGS, True, False).compile().as_text()
+    calls = re.findall(r"%(segment_\w+?)\.\d+ = .*tpu_custom_call", text)
+    assert sorted(set(calls)) == ["segment_minmax_tiled", "segment_sum_tiled"]
+    assert len(calls) == len(sizes)
+
+
 def test_sharded_query_compiles_on_a_4_chip_mesh(topo):
     """The sharded fused query (XLA segment ops under ``jax.shard_map``,
     one collective per pass) partitions over four described chips."""

@@ -214,6 +214,28 @@ def test_explain_names_the_minmax_route(use_pallas):
         assert f"plan.minmax_route: {route}" in rep.text()
 
 
+@pytest.mark.parametrize("page_tiles,use_pallas,pages",
+                         [(None, True, 1), (2, True, None), (None, False, None)])
+def test_explain_shows_the_kernel_pages(page_tiles, use_pallas, pages,
+                                        monkeypatch):
+    """EXPLAIN shows ``plan.pages``: the Pallas calls each segment kernel
+    takes over the plan's largest pass (1 under ``PAGE_TILES``; None where
+    the XLA segment ops run)."""
+    from repro.kernels.segment_reduce import segment_reduce as sr
+
+    if page_tiles is not None:
+        monkeypatch.setattr(sr, "PAGE_TILES", page_tiles)
+    g = with_random_attrs(random_dag(150, 2.0, seed=2), seed=5)
+    sess = Session(g, [QuerySpec("topological", "sum")], device=True,
+                   use_pallas=use_pallas)
+    rep = sess.explain()
+    plan = sess.snapshot().artifacts[0][0][1]
+    want = pages if pages is not None or not use_pallas \
+        else -(-plan.wd_plan.seg_tiles.shape[0] // page_tiles)
+    assert rep.groups[0].terms[0].plan["pages"] == want
+    assert f"plan.pages: {want}" in rep.text()
+
+
 def test_explain_spec_filter_selects_one_group():
     g = with_random_attrs(erdos_renyi(200, 4.0, directed=False, seed=1),
                           seed=2)
