@@ -37,6 +37,7 @@ as flat sorted arrays ready for the device data plane:
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -114,28 +115,25 @@ class DBIndex:
         )
 
     def linked_blocks_mask(self) -> Array:
-        """Bool [num_blocks]: which blocks at least one owner links to."""
-        linked = np.zeros(self.num_blocks, dtype=bool)
-        linked[self.link_block] = True
-        return linked
+        """Bool [num_blocks], read-only: which blocks at least one owner
+        links to.  Scanned once per index (:func:`link_scan`)."""
+        return link_scan(self)[0]
 
-    def garbage_block_fraction(self, linked: Optional[Array] = None) -> float:
+    def garbage_block_fraction(self) -> float:
         """Fraction of blocks no owner links to (zero-link = garbage).
 
         Delete-dominated streams shrink windows: phase-1 merges drop the
         affected owners' links and append smaller secondary blocks, so old
         blocks lose their last link without the links/blocks *growth*
         ratios ever tripping — this is the direct staleness signal for
-        them, shared by :class:`repro.core.streaming.StalenessPolicy` and
-        the pass-1 compaction in
-        :func:`repro.core.engine_jax.patch_plan_dbindex` (which passes its
-        already-computed ``linked`` mask to avoid a second scan).
+        them, shared by :class:`repro.core.streaming.StalenessPolicy`,
+        the service's admission pressure and the pass-1 compaction in
+        :func:`repro.core.engine_jax.patch_plan_dbindex`.  O(1) after the
+        index's first link scan.
         """
         if self.num_blocks == 0:
             return 0.0
-        if linked is None:
-            linked = self.linked_blocks_mask()
-        return 1.0 - int(np.count_nonzero(linked)) / self.num_blocks
+        return 1.0 - link_scan(self)[1] / self.num_blocks
 
     # ----------------------- reverse link map ------------------------ #
     def owners_of_members(self, vertices: Array) -> Array:
@@ -194,6 +192,36 @@ class DBIndex:
                 f"monoid channel upcast: {chan.dtype} -> {ans.dtype}")
             outs.append(ans)
         return a.finalize_np(*outs)
+
+
+# per-thread count of the link scans :func:`link_scan` ran
+_SCANS = threading.local()
+
+
+def link_scans() -> int:
+    """Link scans run on the calling thread so far: a caller takes the
+    difference around its own work to count the scans it caused."""
+    return getattr(_SCANS, "n", 0)
+
+
+def link_scan(index) -> Tuple[Array, int]:
+    """``(linked, count)``: the read-only bool [num_blocks] mask of the
+    blocks some owner links to, and how many there are.
+
+    O(links), so it runs at most once per index object and is memoized
+    on it: a :class:`DBIndex` is immutable, and every structural update
+    builds a new one.  Takes any object with ``num_blocks`` and
+    ``link_block`` (the staleness policy's test doubles).
+    """
+    cached = getattr(index, "_link_scan", None)
+    if cached is None:
+        linked = np.zeros(index.num_blocks, dtype=bool)
+        linked[index.link_block] = True
+        linked.flags.writeable = False
+        cached = (linked, int(np.count_nonzero(linked)))
+        object.__setattr__(index, "_link_scan", cached)
+        _SCANS.n = link_scans() + 1
+    return cached
 
 
 # -------------------------------------------------------------------- #

@@ -269,14 +269,13 @@ def patch_plan_dbindex(
     if index.num_blocks > cap:
         cap = _pow2(index.num_blocks)
     member_block = np.asarray(index.member_block_ids, np.int64)
-    linked = index.linked_blocks_mask()
     # require actual garbage, not just fraction >= threshold: an empty or
     # garbage-free index with compact_garbage == 0.0 would otherwise take
     # the full pass-1 re-layout every batch (a spurious compaction that
     # drops nothing — the delete-everything / zero-block degenerate cases)
-    has_garbage = index.num_blocks > 0 and bool(np.any(~linked))
-    if has_garbage and index.garbage_block_fraction(linked) >= compact_garbage:
-        keep = linked[member_block]
+    garbage = index.garbage_block_fraction()
+    if garbage > 0.0 and garbage >= compact_garbage:
+        keep = index.linked_blocks_mask()[member_block]
         pass1 = build_tile_plan(
             index.block_members[keep], member_block[keep], cap,
             plan.pass1.tm, plan.pass1.ts, headroom=headroom,

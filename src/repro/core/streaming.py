@@ -41,10 +41,10 @@ from repro.core.windows import KHopWindow, TopologicalWindow, filter_attrs
 
 
 def garbage_block_fraction(index) -> float:
-    """Zero-link block fraction (see :meth:`DBIndex.garbage_block_fraction`);
-    tolerates duck-typed policy test doubles that only carry
-    ``num_blocks``/``link_block``/``stats`` (unbound calls keep the metric
-    definition in one place)."""
+    """Zero-link block fraction (see :meth:`DBIndex.garbage_block_fraction`,
+    memoized per index); tolerates duck-typed policy test doubles that
+    only carry ``num_blocks``/``link_block``/``stats`` (unbound calls keep
+    the metric definition in one place)."""
     if getattr(index, "link_block", None) is None:
         return 0.0
     # zero-block guard here as well as in the method: a duck-typed index
@@ -52,7 +52,7 @@ def garbage_block_fraction(index) -> float:
     # whose edges — or whose filtered windows — were all deleted)
     if not getattr(index, "num_blocks", 0):
         return 0.0
-    return DBIndex.garbage_block_fraction(index, DBIndex.linked_blocks_mask(index))
+    return DBIndex.garbage_block_fraction(index)
 
 
 @dataclasses.dataclass(frozen=True)

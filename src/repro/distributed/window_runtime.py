@@ -650,7 +650,7 @@ def patch_sharded_plan(
     # zero-block indices never compact (nothing to drop, and the fraction
     # is defined as 0.0 for them)
     over = (index.num_blocks > 0
-            and index.garbage_block_fraction(linked) >= compact_garbage)
+            and index.garbage_block_fraction() >= compact_garbage)
     compacting = over and fresh_garbage.size > 0
     filter_garbage = compacting or already.size > 0
     if filter_garbage:

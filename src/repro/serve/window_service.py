@@ -58,6 +58,7 @@ import numpy as np
 
 from repro import obs as _obs
 from repro.core.api import QuerySpec, Session, record_recompiles
+from repro.core.dbindex import link_scans
 from repro.obs.slo import SLOTracker
 from repro.serve.flight import FlightRecorder
 
@@ -874,6 +875,9 @@ class AsyncWindowService(WindowService):
         self.deadline_flushes = 0
         self.fill_flushes = 0
         self.backpressure_waits = 0
+        #: DBIndex link scans run by this service's admission checks and
+        #: updates (each index is scanned once, :func:`link_scan`)
+        self.staleness_scans = 0
         self._m_shed = self.obs.counter(
             "repro_shed_total", "tickets rejected/evicted by admission")
         self._m_backpressure = self.obs.counter(
@@ -950,7 +954,10 @@ class AsyncWindowService(WindowService):
         over the remaining headroom to the threshold."""
         pol = self.policy
         p = 0.0
-        for s in self.session.staleness.values():
+        scans = link_scans()
+        staleness = self.session.staleness
+        self._count_scans(link_scans() - scans)
+        for s in staleness.values():
             p = max(
                 p,
                 (s["link_ratio"] - 1.0) / max(pol.max_link_ratio - 1.0, 1e-9),
@@ -961,6 +968,11 @@ class AsyncWindowService(WindowService):
         p = float(min(max(p, 0.0), 1.0))
         self._g_pressure.set(p)
         return p
+
+    def _count_scans(self, scans: int) -> None:
+        if scans:
+            with self._lock:
+                self.staleness_scans += scans
 
     def effective_max_pending(self) -> int:
         """Admission window: ``max_pending`` scaled down by staleness
@@ -1016,6 +1028,7 @@ class AsyncWindowService(WindowService):
             request_class = self.classes[request_class]
         t = self._make_ticket(spec, vertex, values, request_class)
         with self._cv:
+            admit = time.perf_counter()
             while len(self._pending) >= self.effective_max_pending():
                 victim = self._pick_victim(t)
                 if victim is t:
@@ -1044,6 +1057,8 @@ class AsyncWindowService(WindowService):
                 self.backpressure_waits += 1
                 self._m_backpressure.inc()
                 self._cv.wait(timeout=0.01)
+            if t._span is not None:
+                t._span.set(admit_ms=(time.perf_counter() - admit) * 1e3)
             self._pending.append(t)
             self._g_pending.set(len(self._pending))
             self._cv.notify_all()
@@ -1215,7 +1230,9 @@ class AsyncWindowService(WindowService):
                 self.flight.record("wal_commit",
                                    version=self.session.version + 1,
                                    records=int(getattr(batch, "size", 0)))
+            scans = link_scans()
             reports = super().update(batch)
+            self._count_scans(link_scans() - scans)
             if self.wal is not None and self.wal_digests \
                     and hasattr(self.wal, "append_digest"):
                 # the leader's per-version content attestation: written
@@ -1241,6 +1258,7 @@ class AsyncWindowService(WindowService):
             max_pending=self.max_pending,
             effective_max_pending=self.effective_max_pending(),
             pressure=self.pressure(),
+            staleness_scans=self.staleness_scans,
             running=self.running,
         )
         out["class_delay_ms"] = dict(self.class_delay_ms)

@@ -161,3 +161,86 @@ def test_property_topo_dbindex(n, deg, seed):
     idx = build_dbindex(g, w)
     ref = brute_force(g, w, g.attrs["val"], "sum")
     assert np.allclose(idx.query(g.attrs["val"], "sum"), ref)
+
+
+# ------------------------ memoized link scan ------------------------- #
+def _fresh_garbage(idx):
+    linked = np.zeros(idx.num_blocks, dtype=bool)
+    linked[idx.link_block] = True
+    return 1.0 - int(np.count_nonzero(linked)) / idx.num_blocks
+
+
+def test_every_new_index_gets_its_own_link_scan(small_undirected):
+    """The scan is memoized per index object: an index made by
+    ``dataclasses.replace``, ``update_dbindex_batch`` or ``_merge_affected``
+    carries none of its parent's, and each answers as a fresh scan."""
+    import dataclasses
+
+    from repro.core.dbindex import link_scans
+    from repro.core.updates import UpdateBatch, apply_batch, _merge_affected
+
+    g, w = small_undirected, KHopWindow(1)
+    idx = build_dbindex(g, w, method="emc")
+    before = link_scans()
+    assert idx.garbage_block_fraction() == _fresh_garbage(idx) == 0.0
+    assert idx.linked_blocks_mask() is idx.linked_blocks_mask()
+    assert link_scans() == before + 1
+
+    cut = dataclasses.replace(idx, link_block=idx.link_block[:1])
+    assert cut.garbage_block_fraction() == _fresh_garbage(cut) > 0.0
+    assert cut.linked_blocks_mask().sum() == 1
+
+    rng = np.random.default_rng(44)
+    for step in range(3):
+        ei = rng.choice(g.n_edges, 60, replace=False)
+        batch = UpdateBatch.deletes(g.src[ei], g.dst[ei])
+        g = apply_batch(g, batch)
+        prev = idx
+        idx, owners = updates.update_dbindex_batch(prev, g, w, batch)
+        assert idx.garbage_block_fraction() == _fresh_garbage(idx), step
+        assert prev.garbage_block_fraction() == _fresh_garbage(prev), step
+    assert idx.garbage_block_fraction() > 0.0, "stream produced no garbage"
+
+    wins = [khop_window_single(g, int(v), 1) for v in owners[:5]]
+    merged = _merge_affected(idx, owners[:5], wins)
+    assert merged.garbage_block_fraction() == _fresh_garbage(merged)
+    assert merged.linked_blocks_mask() is not idx.linked_blocks_mask()
+    assert link_scans() == before + 6
+
+
+def test_memoized_link_mask_is_read_only(small_undirected):
+    idx = build_dbindex(small_undirected, KHopWindow(1), method="emc")
+    mask = idx.linked_blocks_mask()
+    assert not mask.flags.writeable
+    with pytest.raises(ValueError):
+        mask[0] = False
+    assert idx.garbage_block_fraction() == 0.0
+
+
+def test_policy_doubles_pass_through_garbage_block_fraction():
+    """Duck-typed doubles with ``num_blocks``/``link_block`` only (or
+    without ``link_block``, or without blocks) still read through the
+    module-level ``garbage_block_fraction``, once per double."""
+    from repro.core.dbindex import link_scans
+    from repro.core.streaming import garbage_block_fraction
+
+    class ShrunkIdx:
+        num_blocks = 10
+        stats = {"num_links": 50}
+        link_block = np.array([0, 1, 2], np.int32)
+
+    class NoLinks:
+        num_blocks = 100
+        stats = {"num_links": 200}
+
+    class NoBlocks:
+        num_blocks = 0
+        link_block = np.empty(0, np.int32)
+
+    d = ShrunkIdx()
+    before = link_scans()
+    assert garbage_block_fraction(d) == pytest.approx(0.7)
+    assert garbage_block_fraction(d) == garbage_block_fraction(d)
+    assert link_scans() == before + 1
+    assert garbage_block_fraction(NoLinks()) == 0.0
+    assert garbage_block_fraction(NoBlocks()) == 0.0

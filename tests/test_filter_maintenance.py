@@ -183,8 +183,7 @@ def test_delete_everything_stream(kw):
     _delete_all_in_batches(eng)
     assert eng.graph.n_edges == 0
     # drained index: staleness must be well-defined, never reorganizing
-    linked = eng.index.linked_blocks_mask()
-    assert eng.index.garbage_block_fraction(linked) >= 0.0
+    assert eng.index.garbage_block_fraction() >= 0.0
     assert not eng.policy.should_reorganize(
         eng.index, eng._base_links, eng._base_blocks, 5) \
         or eng.index.num_blocks > 0
@@ -207,8 +206,7 @@ def test_zero_block_filter_index_is_safe():
     assert np.array_equal(
         np.asarray(eng.query("sum")),
         brute_force(g, expr, v, "sum", dtype=np.float32))
-    linked = eng.index.linked_blocks_mask()
-    assert eng.index.garbage_block_fraction(linked) == 0.0
+    assert eng.index.garbage_block_fraction() == 0.0
     assert not StalenessPolicy().should_reorganize(eng.index, 0, 0, 5)
     # flip some vertices on: gains on a drained index must still work
     rng = np.random.default_rng(16)
